@@ -6,6 +6,8 @@ by Kairos over a real JAX paged-KV engine on CPU.
 import sys
 
 from repro.agents import BaseAgent, Workflow
+from repro.configs import get_config
+from repro.launch.cache import enable_compile_cache
 from repro.serving import ServingConfig
 
 # Each agent's fixed preamble is declared as a ``system_prompt``: with
@@ -47,12 +49,14 @@ class HumanitiesAgent(BaseAgent):
 
 
 def main():
+    enable_compile_cache()
     # prefix_caching: shared-prefix KV reuse across agent calls (the knob
     # also teaches the dispatcher's memory ramps about the discount)
     wf = Workflow(app_name="QA", config=ServingConfig(
         n_instances=1, num_blocks=128, block_size=8, max_batch=4,
         prefix_caching=True))
-    wf.add_engine("vllm-0", model="qwen3-1.7b")           # reduced variant on CPU
+    # a CPU-sized variant of the model; chip_smoke.py serves it at full size
+    wf.add_engine("vllm-0", model=get_config("qwen3-1.7b").reduced())
     wf.add_agent("Router", Router, use_model="qwen3-1.7b")
     wf.add_agent("MathAgent", MathAgent, use_model="qwen3-1.7b")
     wf.add_agent("HumanitiesAgent", HumanitiesAgent, use_model="qwen3-1.7b")

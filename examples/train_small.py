@@ -11,6 +11,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_config
+from repro.launch.cache import enable_compile_cache
 from repro.models import build_model
 from repro.training.data import DataConfig, PackedStream
 from repro.training.optimizer import make_train_step
@@ -23,6 +24,7 @@ def main():
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--seq", type=int, default=128)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch).reduced()
     model = build_model(cfg)

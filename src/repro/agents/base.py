@@ -11,13 +11,15 @@ from __future__ import annotations
 import queue
 import threading
 import time
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, Optional, Tuple, Union
 
 import jax
 import numpy as np
 
 from repro.agents.messaging import Headers, MessageBus
 from repro.core import Orchestrator
+from repro.configs import get_config
+from repro.configs.base import ModelConfig
 from repro.core.orchestrator import HardwareProfile
 from repro.models import build_model
 from repro.obs.trace import NULL_TRACER, TraceContext, Tracer
@@ -132,18 +134,19 @@ class Workflow:
         return self.cluster.engines if self.cluster is not None else []
 
     # ------------------------------------------------------------------ setup
-    def add_engine(self, name: str, model: str = "qwen3-1.7b", seed: int = 0):
+    def add_engine(self, name: str, model: Union[str, ModelConfig] = "qwen3-1.7b",
+                   seed: int = 0):
         """Instantiate the serving cluster described by ``self.config``,
-        serving the REDUCED variant of the named architecture (CPU
-        container; full configs go through the dry-run).
+        serving ``model`` with weights drawn from ``seed``.  A name serves
+        the architecture at its published size; pass a config (e.g.
+        ``get_config(name).reduced()``) to serve another size.
         ``ServingCluster.from_config`` wires everything the hand-rolled
         loop used to: per-instance runners cloned from one compile,
         orchestrator-backed instance scheduling, OOM fencing feedback,
         the instance schedulers' ``can_admit`` as the dispatcher's admit
         probe — and an engine factory so an attached autoscaler can grow
         the cluster later."""
-        from repro.configs import get_config
-        cfg = get_config(model).reduced()
+        cfg = get_config(model) if isinstance(model, str) else model
         self.vocab_size = cfg.vocab_size
         m = build_model(cfg)
         params = m.init_params(jax.random.PRNGKey(seed))

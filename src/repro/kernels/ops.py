@@ -48,7 +48,7 @@ def ragged_segment_attention(q, k_pool, v_pool, block_tables, positions,
     ``"pallas"`` / ``"interpret"``
         The native segment-tiled kernel (``kernels/ragged_attention.py``):
         grid (segment, kv_head, kv_page), scalar-prefetched per-segment
-        block tables, (L, hd) query tiles with online-softmax scratch,
+        block tables, (L·G, hd) query tiles with online-softmax scratch,
         and per-segment page bounds so a segment only visits pages up to
         ``max(positions) // bs``.
     ``"flat"`` / ``"flat_interpret"`` / ``"flat_ref"``

@@ -8,9 +8,14 @@ HBM->VMEM page copies double-buffered while the MXU reduces the previous
 page.  Accumulation is the standard running-max/denominator (flash)
 reduction in fp32 VMEM scratch.
 
-Grid: (B, KV_heads, num_pages).  Page k/v tiles are (block_size, head_dim)
-with head_dim padded/aligned to 128 by the caller (all assigned configs
-have head_dim in {64, 112, 128}; 112 is padded by Mosaic).
+Grid: (B, KV_heads, num_pages).  The pool is laid out head-major within
+a page, (N, KV, bs, hd), so one (kv_head, page) tile is a contiguous
+(block_size, head_dim) slab: the last two dims of every k/v block are
+(bs, hd), which is what Mosaic's TPU lowering asks of a block (second-
+minor divisible by 8, minor by 128, or equal to the array's dims).  A
+token-major page (bs, KV, hd) would make the block's second-minor dim a
+single KV head out of KV, which the TPU compiler refuses.  A bf16 page
+fills one native (16, 128) tile at bs=16; bs=8 compiles as well.
 """
 from __future__ import annotations
 
@@ -27,8 +32,8 @@ NEG_INF = -1e30
 def _paged_attn_kernel(block_tables_ref,   # (B, nb) SMEM (scalar prefetch)
                        context_lens_ref,   # (B,)   SMEM (scalar prefetch)
                        q_ref,              # (1, 1, G, hd) VMEM
-                       k_ref,              # (1, bs, 1, hd) VMEM (gathered page)
-                       v_ref,              # (1, bs, 1, hd) VMEM
+                       k_ref,              # (1, 1, bs, hd) VMEM (gathered page)
+                       v_ref,              # (1, 1, bs, hd) VMEM
                        o_ref,              # (1, 1, G, hd) VMEM
                        acc_ref,            # (G, hd) f32 scratch
                        m_ref,              # (G, 1) f32 scratch
@@ -52,8 +57,8 @@ def _paged_attn_kernel(block_tables_ref,   # (B, nb) SMEM (scalar prefetch)
     @pl.when(n * bs < cl)
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32)                  # (G, hd)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)            # (bs, hd)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
+        k = k_ref[0, 0].astype(jnp.float32)                  # (bs, hd)
+        v = v_ref[0, 0].astype(jnp.float32)
 
         scores = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
@@ -83,9 +88,11 @@ def paged_attention(q: jnp.ndarray,
                     block_tables: jnp.ndarray,
                     context_lens: jnp.ndarray,
                     interpret: bool = False) -> jnp.ndarray:
-    """q (B,KV,G,hd); pools (N, bs, KV, hd); tables (B, nb); lens (B,)."""
+    """q (B,KV,G,hd); pools (N, KV, bs, hd); tables (B, nb); lens (B,)."""
     b, kv, g, hd = q.shape
-    _, bs, _, _ = k_pool.shape
+    if q.size == 0:        # absent decode part (prefill-only iteration)
+        return q
+    bs = k_pool.shape[2]
     nb = block_tables.shape[1]
     scale = hd ** -0.5
 
@@ -103,7 +110,7 @@ def paged_attention(q: jnp.ndarray,
     # feeding a negative SMEM index to the table
     def page_map(bb, h, n, bt, cl):
         return (bt[bb, jnp.minimum(n, jnp.maximum(cl[bb] - 1, 0) // bs)],
-                0, h, 0)
+                h, 0, 0)
 
     return pl.pallas_call(
         kernel,
@@ -112,8 +119,8 @@ def paged_attention(q: jnp.ndarray,
             grid=grid,
             in_specs=[
                 pl.BlockSpec((1, 1, g, hd), lambda bb, h, n, bt, cl: (bb, h, 0, 0)),
-                pl.BlockSpec((1, bs, 1, hd), page_map),
-                pl.BlockSpec((1, bs, 1, hd), page_map),
+                pl.BlockSpec((1, 1, bs, hd), page_map),
+                pl.BlockSpec((1, 1, bs, hd), page_map),
             ],
             out_specs=pl.BlockSpec((1, 1, g, hd), lambda bb, h, n, bt, cl: (bb, h, 0, 0)),
             scratch_shapes=[

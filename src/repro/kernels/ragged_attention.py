@@ -16,8 +16,12 @@ This kernel is the native formulation:
 * **grid (segment, kv_head, kv_page)** — one online-softmax pass per
   (segment, head) pair, pages innermost so each page of a segment's
   context is DMA'd into VMEM exactly ONCE and reduced against the whole
-  (L, G, hd) query tile (an (L·G, bs) MXU step instead of L separate
-  (G, bs) steps);
+  query slab of that head: the wrapper lays q out as (S, KV, L·G, hd),
+  so each step is one (L·G, bs) MXU product instead of L separate
+  (G, bs) steps;
+* **head-major pages** — the pool is (N, KV, bs, hd), so a (kv_head,
+  page) k/v block is a dense (bs, hd) tile, the block shape the TPU
+  compiler accepts;
 * **scalar-prefetched block tables** — like the decode kernel, the
   per-segment table and page bound live in SMEM and feed the BlockSpec
   index maps, so Pallas double-buffers the HBM→VMEM page copies;
@@ -49,14 +53,14 @@ NEG_INF = -1e30
 
 def _ragged_attn_kernel(block_tables_ref,   # (S, nb) SMEM (scalar prefetch)
                         page_bounds_ref,    # (S,)    SMEM (scalar prefetch)
-                        q_ref,              # (1, L, 1, G, hd) VMEM
-                        pos_ref,            # (1, L, 1) VMEM
-                        k_ref,              # (1, bs, 1, hd) VMEM (gathered page)
-                        v_ref,              # (1, bs, 1, hd) VMEM
-                        o_ref,              # (1, L, 1, G, hd) VMEM
-                        acc_ref,            # (L, G, hd) f32 scratch
-                        m_ref,              # (L, G, 1) f32 scratch
-                        l_ref,              # (L, G, 1) f32 scratch
+                        q_ref,              # (1, 1, L*G, hd) VMEM
+                        pos_ref,            # (1, L*G, 1) VMEM
+                        k_ref,              # (1, 1, bs, hd) VMEM (gathered page)
+                        v_ref,              # (1, 1, bs, hd) VMEM
+                        o_ref,              # (1, 1, L*G, hd) VMEM
+                        acc_ref,            # (L*G, hd) f32 scratch
+                        m_ref,              # (L*G, 1) f32 scratch
+                        l_ref,              # (L*G, 1) f32 scratch
                         *, bs: int, nb: int, scale: float):
     s = pl.program_id(0)
     n = pl.program_id(2)
@@ -71,37 +75,32 @@ def _ragged_attn_kernel(block_tables_ref,   # (S, nb) SMEM (scalar prefetch)
     # clamps to the bound page — no new DMA) and never reduced
     @pl.when(n <= page_bounds_ref[s])
     def _compute():
-        lq, g = q_ref.shape[1], q_ref.shape[3]
-        hd = q_ref.shape[4]
-        q = q_ref[0, :, 0].reshape(lq * g, hd).astype(jnp.float32)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)            # (bs, hd)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
+        q = q_ref[0, 0].astype(jnp.float32)                   # (L*G, hd)
+        k = k_ref[0, 0].astype(jnp.float32)                   # (bs, hd)
+        v = v_ref[0, 0].astype(jnp.float32)
 
         scores = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale       # (L*G, bs)
-        scores = scores.reshape(lq, g, bs)
-        # segment-blocked causal mask: query (s, j) at absolute position
-        # pos[j] sees pool tokens of its own table at indices <= pos[j]
+        # segment-blocked causal mask: query row r at absolute position
+        # pos[r] sees pool tokens of its own table at indices <= pos[r]
         token_idx = n * bs + jax.lax.broadcasted_iota(
-            jnp.int32, (lq, g, bs), 2)
-        pos = pos_ref[0]                                      # (L, 1)
-        scores = jnp.where(pos[:, :, None] >= token_idx, scores, NEG_INF)
+            jnp.int32, scores.shape, 1)
+        scores = jnp.where(pos_ref[0] >= token_idx, scores, NEG_INF)
 
-        m_prev = m_ref[...]                                   # (L, G, 1)
-        m_new = jnp.maximum(m_prev, jnp.max(scores, axis=2, keepdims=True))
+        m_prev = m_ref[...]                                   # (L*G, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(scores, axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(scores - m_new)                           # (L, G, bs)
-        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=2, keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            p.reshape(lq * g, bs), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32).reshape(lq, g, hd)
+        p = jnp.exp(scores - m_new)                           # (L*G, bs)
+        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot(
+            p, v, preferred_element_type=jnp.float32)
         m_ref[...] = m_new
 
     @pl.when(n == nb - 1)
     def _finish():
         denom = jnp.maximum(l_ref[...], 1e-20)
-        o_ref[0, :, 0] = (acc_ref[...] / denom).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_ref[...] / denom).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -111,48 +110,54 @@ def ragged_segment_attention(q: jnp.ndarray,
                              block_tables: jnp.ndarray,
                              positions: jnp.ndarray,
                              interpret: bool = False) -> jnp.ndarray:
-    """q (S, L, KV, G, hd); pools (N, bs, KV, hd); tables (S, nb);
+    """q (S, L, KV, G, hd); pools (N, KV, bs, hd); tables (S, nb);
     positions (S, L) absolute position per query token.  Returns
     (S, L, KV, G, hd).  See ``kernels/ref.py`` for mask semantics."""
     s, lq, kv, g, hd = q.shape
     if q.size == 0:        # absent prefill part (decode-only iteration)
         return q
-    _, bs, _, _ = k_pool.shape
+    bs = k_pool.shape[2]
     nb = block_tables.shape[1]
     scale = hd ** -0.5
     # last page each segment can attend: max position // bs (padding rows
     # sit at position 0 and never raise the bound)
     page_bounds = jnp.max(positions, axis=1) // bs            # (S,)
-    pos3 = positions.reshape(s, lq, 1)
+    # one (L*G, hd) query slab per (segment, kv head): row r = j*G + g is
+    # token j's g-th grouped head, so the slab's last two dims are dense
+    # tiles on the TPU and the MXU sees L*G rows per page
+    rows = lq * g
+    q_t = q.transpose(0, 2, 1, 3, 4).reshape(s, kv, rows, hd)
+    pos_rows = jnp.repeat(positions, g, axis=1).reshape(s, rows, 1)
 
     kernel = functools.partial(_ragged_attn_kernel, bs=bs, nb=nb, scale=scale)
     grid = (s, kv, nb)
 
     def page_map(ss, h, n, bt, bounds):
-        return (bt[ss, jnp.minimum(n, bounds[ss])], 0, h, 0)
+        return (bt[ss, jnp.minimum(n, bounds[ss])], h, 0, 0)
 
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=grid,
             in_specs=[
-                pl.BlockSpec((1, lq, 1, g, hd),
-                             lambda ss, h, n, bt, bounds: (ss, 0, h, 0, 0)),
-                pl.BlockSpec((1, lq, 1),
+                pl.BlockSpec((1, 1, rows, hd),
+                             lambda ss, h, n, bt, bounds: (ss, h, 0, 0)),
+                pl.BlockSpec((1, rows, 1),
                              lambda ss, h, n, bt, bounds: (ss, 0, 0)),
-                pl.BlockSpec((1, bs, 1, hd), page_map),
-                pl.BlockSpec((1, bs, 1, hd), page_map),
+                pl.BlockSpec((1, 1, bs, hd), page_map),
+                pl.BlockSpec((1, 1, bs, hd), page_map),
             ],
             out_specs=pl.BlockSpec(
-                (1, lq, 1, g, hd),
-                lambda ss, h, n, bt, bounds: (ss, 0, h, 0, 0)),
+                (1, 1, rows, hd),
+                lambda ss, h, n, bt, bounds: (ss, h, 0, 0)),
             scratch_shapes=[
-                pltpu.VMEM((lq, g, hd), jnp.float32),
-                pltpu.VMEM((lq, g, 1), jnp.float32),
-                pltpu.VMEM((lq, g, 1), jnp.float32),
+                pltpu.VMEM((rows, hd), jnp.float32),
+                pltpu.VMEM((rows, 1), jnp.float32),
+                pltpu.VMEM((rows, 1), jnp.float32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((s, lq, kv, g, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((s, kv, rows, hd), q.dtype),
         interpret=interpret,
-    )(block_tables, page_bounds, q, pos3, k_pool, v_pool)
+    )(block_tables, page_bounds, q_t, pos_rows, k_pool, v_pool)
+    return out.reshape(s, kv, lq, g, hd).transpose(0, 2, 1, 3, 4)

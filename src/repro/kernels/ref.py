@@ -11,6 +11,12 @@ import jax.numpy as jnp
 NEG_INF = -1e30
 
 
+def _tokens(pages: jnp.ndarray, s_max: int) -> jnp.ndarray:
+    """Gathered pages (B, nb, KV, bs, hd) -> token-major (B, nb*bs, KV, hd)."""
+    b, _, kv, _, hd = pages.shape
+    return pages.transpose(0, 1, 3, 2, 4).reshape(b, s_max, kv, hd)
+
+
 def paged_attention_ref(q: jnp.ndarray,
                         k_pool: jnp.ndarray,
                         v_pool: jnp.ndarray,
@@ -19,19 +25,19 @@ def paged_attention_ref(q: jnp.ndarray,
     """Decode attention over a paged KV cache.
 
     q:            (B, KV, G, hd)   — grouped queries (H = KV*G)
-    k_pool/v_pool:(N_blocks, bs, KV, hd)
+    k_pool/v_pool:(N_blocks, KV, bs, hd) — head-major pages
     block_tables: (B, max_blocks)  int32 physical block ids
     context_lens: (B,)             int32 valid tokens per sequence
     returns:      (B, KV, G, hd)
     """
     b, kv, g, hd = q.shape
-    bs = k_pool.shape[1]
+    bs = k_pool.shape[2]
     max_blocks = block_tables.shape[1]
     s_max = max_blocks * bs
 
     # gather pages -> (B, S_max, KV, hd)
-    k = k_pool[block_tables].reshape(b, s_max, kv, hd)
-    v = v_pool[block_tables].reshape(b, s_max, kv, hd)
+    k = _tokens(k_pool[block_tables], s_max)
+    v = _tokens(v_pool[block_tables], s_max)
 
     scores = jnp.einsum("bkgd,btkd->bkgt", q, k).astype(jnp.float32) / (hd ** 0.5)
     valid = jnp.arange(s_max)[None, :] < context_lens[:, None]          # (B, S)
@@ -62,7 +68,7 @@ def ragged_segment_attention_ref(q: jnp.ndarray,
     caller discards.
 
     q:            (S, L, KV, G, hd) — grouped queries, tiled per segment
-    k_pool/v_pool:(N_blocks, bs, KV, hd)
+    k_pool/v_pool:(N_blocks, KV, bs, hd) — head-major pages
     block_tables: (S, max_blocks)   int32 — one table per segment
     positions:    (S, L)            int32 absolute position per token
     returns:      (S, L, KV, G, hd)
@@ -79,14 +85,14 @@ def ragged_segment_attention_ref(q: jnp.ndarray,
     s, _, kv, g, hd = q.shape
     if q.size == 0:        # absent prefill part (decode-only iteration)
         return q
-    bs = k_pool.shape[1]
+    bs = k_pool.shape[2]
     nb = block_tables.shape[1]
     s_max = nb * bs
     bounds = jnp.max(positions, axis=1) // bs                    # (S,)
     page_idx = jnp.minimum(jnp.arange(nb)[None, :], bounds[:, None])
     bt = jnp.take_along_axis(block_tables, page_idx, axis=1)
-    k = k_pool[bt].reshape(s, s_max, kv, hd)
-    v = v_pool[bt].reshape(s, s_max, kv, hd)
+    k = _tokens(k_pool[bt], s_max)
+    v = _tokens(v_pool[bt], s_max)
     scores = jnp.einsum("slkgd,stkd->skglt", q, k).astype(jnp.float32) / (hd ** 0.5)
     keep = positions[:, None, None, :, None] >= \
         jnp.arange(s_max)[None, None, None, None, :]

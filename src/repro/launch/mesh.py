@@ -3,6 +3,8 @@
 A FUNCTION (not a module-level constant) so importing this module never
 touches jax device state.  Production target: TPU v5e, 16x16 = 256 chips
 per pod; the multi-pod mesh adds a leading "pod" axis (2 pods = 512 chips).
+Every axis is ``Auto``: the models place activations with
+``with_sharding_constraint`` and leave the rest to the partitioner.
 
 The serving stack uses *host-level* meshes: :func:`make_local_mesh` for
 one tensor-parallel instance, :func:`make_slice_meshes` to carve the
@@ -16,12 +18,13 @@ from __future__ import annotations
 from typing import List, Optional, Sequence
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_local_mesh(model_parallel: int = 1,
@@ -45,7 +48,8 @@ def make_local_mesh(model_parallel: int = 1,
             f"{n} available device(s); pick a factor of the device count "
             f"(or pass an explicit `devices=` slice)")
     return jax.make_mesh((n // model_parallel, model_parallel),
-                         ("data", "model"), devices=devs)
+                         ("data", "model"), devices=devs,
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def make_slice_meshes(n_slices: int, model_parallel: int = 1,

@@ -199,12 +199,12 @@ def cache_pspecs(cfg: ModelConfig, shape: ShapeConfig, mesh: Mesh, cache_shape) 
 # serving (paged-engine) rules
 # --------------------------------------------------------------------------- #
 
-# Paged KV pool (L, 2, num_blocks, block_size, n_kv, hd): shard the
+# Paged KV pool (L, 2, num_blocks, n_kv, block_size, hd): shard the
 # KV-head dim over "model" — the pool's logical shape is unchanged, the
 # BlockManager stays head-agnostic (block ids address whole cross-shard
 # pages), and each tensor-parallel shard holds exactly the head slice
 # its megatron-sharded K/V projections produce.
-POOL_PSPEC = P(None, None, None, None, "model", None)
+POOL_PSPEC = P(None, None, None, "model", None, None)
 
 
 def serving_param_specs(params, cfg: ModelConfig, mesh: Mesh):

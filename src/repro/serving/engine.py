@@ -19,7 +19,6 @@ from typing import Callable, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.scheduler import SchedulerPolicy
@@ -96,11 +95,20 @@ def _layer_finish(xx, o, lp, cfg, axis: Optional[str] = None):
     return xx + f
 
 
+def _write_tokens(pages, slots, x, bs: int):
+    """Write token rows ``x`` (T, n_kv, hd) into one layer's pages
+    (N, n_kv, bs, hd) at flat slots ``block * bs + offset``; a slot past
+    the pool (``N * bs``) drops its row."""
+    return pages.at[slots // bs, :, slots % bs].set(x, mode="drop")
+
+
 class PagedModelRunner:
     """Runs a :class:`LanguageModel` against a paged KV pool.
 
-    Pool: (L, 2, num_blocks, block_size, n_kv, hd).  Decode is batched
-    across sequences at arbitrary positions via block tables.
+    Pool: (L, 2, num_blocks, n_kv, block_size, hd) — head-major pages,
+    so one (kv_head, block) tile is the dense (block_size, hd) slab the
+    Pallas kernels DMA.  Decode is batched across sequences at arbitrary
+    positions via block tables.
 
     **In-place pool semantics** (``donate_pool``, default on): every
     jitted step function *donates* the pool argument, so XLA writes the
@@ -251,8 +259,8 @@ class PagedModelRunner:
         the KV-head shard spec (or the default device when meshless)."""
         cfg = self.cfg
         pool = jnp.zeros(
-            (cfg.num_layers, 2, self.num_blocks, self.block_size,
-             cfg.num_kv_heads, cfg.resolved_head_dim), self.model.dtype)
+            (cfg.num_layers, 2, self.num_blocks, cfg.num_kv_heads,
+             self.block_size, cfg.resolved_head_dim), self.model.dtype)
         if self.mesh is not None:
             pool = jax.device_put(pool,
                                   NamedSharding(self.mesh, self._pool_pspec))
@@ -260,12 +268,12 @@ class PagedModelRunner:
 
     def _smap(self, fn, in_specs, out_specs):
         """shard_map a step body over this runner's mesh slice.
-        check_rep=False: the Pallas/interpret attention backends defeat
+        check_vma=False: the Pallas/interpret attention backends defeat
         replication inference, and every replicated output here is
         replicated by construction (psum'ed activations x replicated
         head)."""
-        return shard_map(fn, mesh=self.mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
+        return jax.shard_map(fn, mesh=self.mesh, in_specs=in_specs,
+                             out_specs=out_specs, check_vma=False)
 
     @property
     def n_dispatches(self) -> int:
@@ -319,10 +327,9 @@ class PagedModelRunner:
 
     def jit_cache_size(self) -> int:
         """Total compiled specializations across the runner's jitted entry
-        points — the recompile counter the fusion benchmark/CI tracks.
-        ``_cache_size`` is a private jax API (0.4.x); degrade to 0 rather
-        than break benchmarks/tests if a future release drops it."""
-        return sum(getattr(f, "_cache_size", lambda: 0)() for f in
+        points — the recompile counter the fusion benchmark/CI tracks
+        (``_cache_size`` of each jitted callable, JAX 0.9)."""
+        return sum(f._cache_size() for f in
                    (self._decode_fn, self._prefill_fn, self._suffix_fn,
                     self._fused_fn, self._scatter_fn, self._copy_block_fn,
                     self._write_blocks_fn))
@@ -330,7 +337,7 @@ class PagedModelRunner:
     # -- block-granular KV transfer (live request migration) ------------------
     def read_blocks(self, block_ids: Sequence[int]) -> np.ndarray:
         """Gather the KV of ``block_ids`` to host:
-        (L, 2, n_blocks, block_size, n_kv, hd) numpy.  The gather is a
+        (L, 2, n_blocks, n_kv, block_size, hd) numpy.  The gather is a
         fresh buffer — the pool itself is only *read*, never donated, so
         ``pool_address()`` is unchanged by this call (the migration tests
         witness exactly that).  Like every pool read it must run between
@@ -389,7 +396,7 @@ class PagedModelRunner:
             kv = jnp.pad(kv, [(0, 0), (0, 0), (0, 0), (0, nb * bs - s),
                               (0, 0), (0, 0)])
             kv = kv.reshape(kv.shape[0], 2, nb, bs, *kv.shape[4:])
-            return pool.at[:, :, bt].set(kv)
+            return pool.at[:, :, bt].set(kv.swapaxes(3, 4))
 
         return scatter
 
@@ -435,6 +442,7 @@ class PagedModelRunner:
         cfg = self._lcfg
         axis = self._tp_axis
         hd = cfg.resolved_head_dim
+        bs = self.block_size
 
         def step(params, pool, tokens, ctx_bt, write_idx, n_cached):
             s = tokens.shape[0]
@@ -451,9 +459,9 @@ class PagedModelRunner:
                 # resident K/V: gather the covering pages (already rope'd
                 # at write), keep the first n_cached rows — the last page
                 # may be partially filled by an earlier chunk
-                pk = pool_layer[0][ctx_bt].reshape(
+                pk = pool_layer[0][ctx_bt].swapaxes(1, 2).reshape(
                     -1, cfg.num_kv_heads, hd)[:n_cached]
-                pv = pool_layer[1][ctx_bt].reshape(
+                pv = pool_layer[1][ctx_bt].swapaxes(1, 2).reshape(
                     -1, cfg.num_kv_heads, hd)[:n_cached]
                 kf = jnp.concatenate([pk[None], k], axis=1)   # (1, P+S, kv, hd)
                 vf = jnp.concatenate([pv[None], v], axis=1)
@@ -465,9 +473,9 @@ class PagedModelRunner:
 
             x, kvs = jax.lax.scan(body, x, (params["layers"], pool))
             # scatter the chunk's KV at its exact token slots — per-token
-            # flat indices, so chunks may start or end mid-block
-            flat = pool.reshape(*pool.shape[:2], -1, cfg.num_kv_heads, hd)
-            pool = flat.at[:, :, write_idx].set(kvs).reshape(pool.shape)
+            # (block, offset) indices, so chunks may start or end mid-block
+            pool = pool.at[:, :, write_idx // bs, :, write_idx % bs].set(
+                kvs.transpose(2, 0, 1, 3, 4))
             x = rms_norm(x, params["final_norm"], cfg.norm_eps)
             logits = lm_logits(params, x[:, -1], cfg)
             return logits[0], pool
@@ -506,6 +514,7 @@ class PagedModelRunner:
         cfg = self._lcfg
         axis = self._tp_axis
         hd = cfg.resolved_head_dim
+        bs = self.block_size
         backend = self.backend
         ragged_backend = self.ragged_backend
 
@@ -532,10 +541,8 @@ class PagedModelRunner:
                 # tokens (its own chunk's prefix, or another chunk that
                 # shares its cached-prefix blocks) straight from the pool;
                 # padding rows carry an out-of-range slot and drop
-                kp = pool_layer[0].reshape(-1, cfg.num_kv_heads, hd).at[
-                    write_slots].set(k[0], mode="drop").reshape(pool_layer[0].shape)
-                vp = pool_layer[1].reshape(-1, cfg.num_kv_heads, hd).at[
-                    write_slots].set(v[0], mode="drop").reshape(pool_layer[1].shape)
+                kp = _write_tokens(pool_layer[0], write_slots, k[0], bs)
+                vp = _write_tokens(pool_layer[1], write_slots, v[0], bs)
                 g = cfg.num_heads // cfg.num_kv_heads
                 qg = q[0].reshape(-1, cfg.num_kv_heads, g, hd)
                 # chunk rows attend as dense (Sp, L) tiles through the
@@ -586,10 +593,8 @@ class PagedModelRunner:
                 flat = block_tables[jnp.arange(tokens.shape[0]), positions // bs] * bs \
                     + positions % bs
                 flat = jnp.where(live, flat, pool_layer[0].shape[0] * bs)
-                kp = pool_layer[0].reshape(-1, cfg.num_kv_heads, hd).at[flat].set(
-                    k[:, 0], mode="drop").reshape(pool_layer[0].shape)
-                vp = pool_layer[1].reshape(-1, cfg.num_kv_heads, hd).at[flat].set(
-                    v[:, 0], mode="drop").reshape(pool_layer[1].shape)
+                kp = _write_tokens(pool_layer[0], flat, k[:, 0], bs)
+                vp = _write_tokens(pool_layer[1], flat, v[:, 0], bs)
                 g = cfg.num_heads // cfg.num_kv_heads
                 qg = q.reshape(q.shape[0], cfg.num_kv_heads, g, hd)
                 o = kops.paged_attention(qg, kp, vp, block_tables, ctx, backend=backend)

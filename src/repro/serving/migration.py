@@ -70,7 +70,7 @@ class MigrationError(RuntimeError):
 class RequestSnapshot:
     """A request's transferable state, block-granular and host-resident."""
     req: Request
-    kv: np.ndarray                 # (L, 2, n_blocks, block_size, n_kv, hd)
+    kv: np.ndarray                 # (L, 2, n_blocks, n_kv, block_size, hd)
     hashes: List[int]              # full-block prompt hash chain (may be [])
     n_resident_tokens: int         # prefilled_len + output_len at snapshot
     pending_token: Optional[int]   # sampled-but-not-fed token (None mid-prefill)

@@ -3,6 +3,7 @@ engine with identifier propagation and workflow reconstruction."""
 import pytest
 
 from repro.agents import BaseAgent, Workflow
+from repro.configs import get_config
 from repro.serving import ServingConfig
 
 
@@ -24,7 +25,7 @@ class Stage2(BaseAgent):
 def test_two_stage_workflow_end_to_end():
     wf = Workflow(app_name="test", config=ServingConfig(
         n_instances=1, num_blocks=64, block_size=8, max_batch=4))
-    wf.add_engine("e0", model="qwen3-1.7b")
+    wf.add_engine("e0", model=get_config("qwen3-1.7b").reduced())
     wf.add_agent("Stage1", Stage1)
     wf.add_agent("Stage2", Stage2)
     ids = [wf.submit_task("Stage1", {"q": i}) for i in range(3)]

@@ -281,10 +281,11 @@ def test_workflow_wires_cluster_probe_and_feedback():
     """Workflow.add_engine builds a ServingCluster whose dispatcher
     probes ``can_admit`` (not the old ad-hoc queue-length lambda)."""
     from repro.agents import Workflow
+    from repro.configs import get_config
     from repro.serving import ServingConfig
     wf = Workflow(app_name="t", config=ServingConfig(
         n_instances=2, num_blocks=32, block_size=8, max_batch=4))
-    wf.add_engine("e0")
+    wf.add_engine("e0", model=get_config("qwen3-1.7b").reduced())
     assert wf.cluster is not None
     assert wf.cluster.dispatcher.admit_probe == wf.cluster.can_admit
     assert wf.cluster.oom_feedback

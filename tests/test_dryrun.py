@@ -29,7 +29,8 @@ def test_dryrun_subset_compiles_both_meshes():
     cmd = [sys.executable, "-m", "repro.launch.dryrun", "--both-meshes", "--out", out]
     for arch, shape in CASES:
         cmd += ["--arch", arch, "--shape", shape]
-    env = {"PYTHONPATH": str(ROOT / "src"), "PATH": "/usr/bin:/bin:/usr/local/bin"}
+    env = {"PYTHONPATH": str(ROOT / "src"), "PATH": "/usr/bin:/bin:/usr/local/bin",
+           "JAX_PLATFORMS": "cpu"}
     proc = subprocess.run(cmd, capture_output=True, text=True, cwd=ROOT,
                           env=env, timeout=1200)
     assert proc.returncode == 0, proc.stdout[-2000:] + proc.stderr[-2000:]

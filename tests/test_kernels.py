@@ -13,8 +13,8 @@ from repro.kernels.ref import paged_attention_ref, ragged_segment_attention_ref
 def _make_case(key, b, kv, g, hd, bs, nb_per_seq, n_pool, dtype):
     ks = jax.random.split(key, 5)
     q = jax.random.normal(ks[0], (b, kv, g, hd), dtype)
-    k_pool = jax.random.normal(ks[1], (n_pool, bs, kv, hd), dtype)
-    v_pool = jax.random.normal(ks[2], (n_pool, bs, kv, hd), dtype)
+    k_pool = jax.random.normal(ks[1], (n_pool, kv, bs, hd), dtype)
+    v_pool = jax.random.normal(ks[2], (n_pool, kv, bs, hd), dtype)
     # unique block ids per sequence (like a real allocator would hand out)
     perm = jax.random.permutation(ks[3], n_pool)[: b * nb_per_seq]
     block_tables = perm.reshape(b, nb_per_seq).astype(jnp.int32)
@@ -51,8 +51,8 @@ def test_paged_attention_single_token_context():
     q, k_pool, v_pool, bt, _ = _make_case(jax.random.PRNGKey(0), b, kv, g, hd, bs, 2, 8, jnp.float32)
     cl = jnp.array([1], jnp.int32)
     out = paged_attention(q, k_pool, v_pool, bt, cl, interpret=True)
-    expect = jnp.broadcast_to(k_pool[bt[0, 0], 0][None, :, None], (b, kv, g, hd)) * 0 \
-        + v_pool[bt[0, 0], 0].transpose(0, 1)[None, :, None, :]
+    expect = jnp.broadcast_to(v_pool[bt[0, 0], :, 0][None, :, None, :],
+                              (b, kv, g, hd))
     np.testing.assert_allclose(np.asarray(out), np.asarray(expect), rtol=1e-5, atol=1e-5)
 
 
@@ -80,8 +80,8 @@ def _make_ragged_case(key, seg_specs, kv, g, hd, bs, nb, n_pool, dtype):
     — mid-block boundaries whenever n_cached % bs != 0 — tiled into a
     dense (S, L) block with padding rows where length < L."""
     ks = jax.random.split(key, 4)
-    k_pool = jax.random.normal(ks[0], (n_pool, bs, kv, hd), dtype)
-    v_pool = jax.random.normal(ks[1], (n_pool, bs, kv, hd), dtype)
+    k_pool = jax.random.normal(ks[0], (n_pool, kv, bs, hd), dtype)
+    v_pool = jax.random.normal(ks[1], (n_pool, kv, bs, hd), dtype)
     perm = np.asarray(jax.random.permutation(ks[2], n_pool))
     s, lmax = len(seg_specs), max(n for n, _ in seg_specs)
     tables = np.stack([perm[i * nb:(i + 1) * nb] for i in range(s)])
