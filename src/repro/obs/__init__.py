@@ -6,6 +6,7 @@ from repro.obs.trace import (
     NullTracer,
     TraceContext,
     Tracer,
+    span,
 )
 from repro.obs.metrics import (
     Counter,
@@ -40,7 +41,7 @@ from repro.obs.export import (
 
 __all__ = [
     "EVENT_KINDS", "NULL_TRACER", "Event", "NullTracer", "TraceContext",
-    "Tracer",
+    "Tracer", "span",
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "merge_snapshots",
     "rollup_by_role",
     "CriticalPath", "StageSpan", "critical_path", "spans_from_events",

@@ -32,6 +32,10 @@ Design constraints, in order:
   simulated timestamps (``emit(..., ts=now)``); the real path defaults
   to the tracer's ``clock``.  Sim-vs-real breakdowns are diffable.
 
+Beside the request events, :func:`span` marks each layer of the serving
+step (control plane, instance scheduler, engine, device wait) as a host
+span in the JAX profiler's trace, on the same clock as the device's ops.
+
 Event taxonomy (``kind``):
 
 ======================  =====================================================
@@ -43,7 +47,7 @@ Event taxonomy (``kind``):
                         ``data['cached']`` = prefix-cache tokens served free
 ``prefill-chunk``       one prompt chunk composed into an iteration
                         (``data['start']/['end']/['last']``)
-``first-token``         the request's first generated token was computed
+``first-token``         the request's first generated token reached the host
 ``decode``              one decode token booked for the request
 ``iteration``           one engine iteration composed (``data['n_chunks']``,
                         ``['n_decode']``, ``['n_tokens']``)
@@ -82,6 +86,8 @@ from __future__ import annotations
 import dataclasses
 import time
 from typing import Dict, List, NamedTuple, Optional
+
+import jax
 
 EVENT_KINDS = (
     "submit", "dispatch", "migrate-candidate", "admit", "prefill-chunk",
@@ -220,3 +226,26 @@ class NullTracer(Tracer):
 
 
 NULL_TRACER = NullTracer()
+
+
+def span(name: str) -> jax.profiler.TraceAnnotation:
+    """A host span for the JAX profiler's trace: ``with span(name): ...``.
+
+    It lands in the same ``.xplane.pb`` as the device's ops, on the
+    same clock, so each device idle gap can be charged to the span open
+    on the host at that moment.  Always on: outside a profiler session
+    it costs a few Python operations.  The serving step's spans:
+
+    * ``kairos.step``: one ``ServingCluster.step``, parent of the rest;
+    * ``kairos.ctrl.tick``: recovery tick, autoscaler, shed sweep and
+      balancer tick (control plane);
+    * ``kairos.sched.plan`` / ``kairos.sched.flatten``: one engine's
+      ``BatchScheduler.plan`` and ``flatten_plan`` (instance scheduler);
+    * ``kairos.engine.dispatch``: the fused step issued on a shape the
+      runner has issued before; ``kairos.engine.compile``: the same on
+      a new shape (trace, lower, compile or load from the cache);
+    * ``kairos.engine.collect``: ``LLMEngine.collect``;
+    * ``kairos.engine.sync``: the host blocked until a step's tokens
+      arrive, nested in whichever span needed them.
+    """
+    return jax.profiler.TraceAnnotation(name)

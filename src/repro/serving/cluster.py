@@ -58,7 +58,7 @@ from concurrent.futures import ThreadPoolExecutor
 from typing import Callable, List, Optional, Sequence
 
 from repro.obs.metrics import merge_snapshots
-from repro.obs.trace import NULL_TRACER, Tracer
+from repro.obs.trace import NULL_TRACER, Tracer, span
 from repro.serving.config import ServingConfig
 from repro.serving.engine import LLMEngine
 from repro.serving.faults import FaultInjector, FaultPlan, InstanceCrashed
@@ -370,21 +370,25 @@ class ServingCluster:
         prefill-role instance are handed to decode-capable instances
         (``serving/handoff.py``), one gathered donated dispatch per
         (source, target) batch."""
-        now = self.clock() if now is None else now
+        with span("kairos.step"):
+            return self._step(self.clock() if now is None else now)
+
+    def _step(self, now: float) -> List[Request]:
         finished: List[Request] = []
         if self._shed_at_submit:
             # requests shed at the submit door surface here so callers
             # waiting on step() results unblock
             finished.extend(self._shed_at_submit)
             self._shed_at_submit.clear()
-        self.recovery.tick(self, now)
-        if self.autoscaler is not None:
-            # engines are synced between steps, which is exactly when
-            # live migration (scale-down drain) is legal
-            finished.extend(self.autoscaler.step(self, now))
-        if self.shedder is not None:
-            finished.extend(self._shed_sweep(now))
-        self.balancer.tick(now)
+        with span("kairos.ctrl.tick"):
+            self.recovery.tick(self, now)
+            if self.autoscaler is not None:
+                # engines are synced between steps, which is exactly when
+                # live migration (scale-down drain) is legal
+                finished.extend(self.autoscaler.step(self, now))
+            if self.shedder is not None:
+                finished.extend(self._shed_sweep(now))
+            self.balancer.tick(now)
         for group in self._role_groups():
             if self.pipelined and len(group) > 1:
                 if self._pool is None:
@@ -400,34 +404,20 @@ class ServingCluster:
                         finished.extend(self.recovery.on_crash(self, e, now))
                         continue
                     finished.extend(self._collect(e, now))
-                    self.recovery.check_step_deadline(
-                        self, e, e.last_step_wall, now)
-            elif self.pipelined:
-                # single engine: nothing to overlap across instances —
-                # skip the worker handoff, keep the deferred host sync
-                e = group[0]
-                try:
-                    t0 = time.monotonic()
-                    e.dispatch_iteration()
-                    e.last_step_wall = time.monotonic() - t0
-                except InstanceCrashed:
-                    finished.extend(self.recovery.on_crash(self, e, now))
-                    continue
-                finished.extend(self._collect(e, now))
-                self.recovery.check_step_deadline(
-                    self, e, e.last_step_wall, now)
+                    self._check_step_deadline(e, now)
             else:
+                # one engine (nothing to overlap across instances: skip
+                # the worker handoff, keep the deferred host sync), or the
+                # serial loop, which syncs each engine before the next
                 for e in group:
                     try:
-                        t0 = time.monotonic()
                         e.dispatch_iteration()
-                        e.last_step_wall = time.monotonic() - t0
                     except InstanceCrashed:
                         finished.extend(self.recovery.on_crash(self, e, now))
                         continue
-                    finished.extend(self._collect(e, now, force_sync=True))
-                    self.recovery.check_step_deadline(
-                        self, e, e.last_step_wall, now)
+                    finished.extend(self._collect(
+                        e, now, force_sync=not self.pipelined))
+                    self._check_step_deadline(e, now)
         if any(e.role == "prefill" for e in self.engines):
             from repro.serving.handoff import drive_handoffs
             hs = drive_handoffs(self, now)
@@ -454,14 +444,18 @@ class ServingCluster:
     def _dispatch_one(e: LLMEngine):
         """Worker body: issue the engine's iteration and absorb its
         device wait here, off the control-plane thread.  Engine state is
-        instance-local, so workers never contend.  The measured wall time
-        (dispatch + device wait) feeds the straggler step-deadline check;
-        the write is engine-local, read post-collect on the control
-        plane."""
-        t0 = time.monotonic()
+        instance-local, so workers never contend.  The sync stamps the
+        step's wall time (dispatch + device wait) on the engine, read
+        post-collect on the control plane."""
         e.dispatch_iteration()
         e.sync()
-        e.last_step_wall = time.monotonic() - t0
+
+    def _check_step_deadline(self, e: LLMEngine, now: float):
+        """Straggler check on the engine's newest step whose tokens
+        reached the host (on the one-engine path, the previous step: its
+        tokens sync in this step's flatten).  Each step is checked once."""
+        wall, e.last_step_wall = e.last_step_wall, 0.0
+        self.recovery.check_step_deadline(self, e, wall, now)
 
     def _collect(self, e: LLMEngine, now: float,
                  force_sync: bool = False) -> List[Request]:

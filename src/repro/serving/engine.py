@@ -24,7 +24,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro.core.scheduler import SchedulerPolicy
 from repro.kernels import ops as kops
 from repro.obs.metrics import MetricsRegistry
-from repro.obs.trace import NULL_TRACER, Tracer
+from repro.obs.trace import NULL_TRACER, Tracer, span
 from repro.models import attention as attn_mod
 from repro.models.layers import embed_tokens, lm_logits, rms_norm, swiglu
 from repro.models.model import LanguageModel
@@ -204,8 +204,10 @@ class PagedModelRunner:
         # launched device computation).  Plain device->host transfers of
         # already-computed arrays (np.asarray on a result) execute no op
         # and are not counted on either path.
-        self.metrics = MetricsRegistry()
-        self.n_dispatches = 0
+        self._new_metrics()
+        # argument shapes of the fused step issued so far (shared with
+        # clones, which share its compiled specializations)
+        self._issued: set = set()
         if self._tp_axis is None:
             decode = self._build_decode()
             fused = self._build_fused()
@@ -274,6 +276,14 @@ class PagedModelRunner:
         head)."""
         return jax.shard_map(fn, mesh=self.mesh, in_specs=in_specs,
                              out_specs=out_specs, check_vma=False)
+
+    def _new_metrics(self):
+        self.metrics = MetricsRegistry()
+        self.n_dispatches = 0
+        # fused steps issued on a shape new to the runner (each traces,
+        # lowers and compiles or loads it), and the seconds they took
+        self.metrics.counter("compiles")
+        self.metrics.counter("compile_s")
 
     @property
     def n_dispatches(self) -> int:
@@ -498,16 +508,29 @@ class PagedModelRunner:
         call's result in the same statement, so the dead input reference
         can never be observed, and the next-token output is a distinct
         (non-aliased) buffer — deferring its host sync via
-        :class:`TokenBuffer` never touches donated storage."""
+        :class:`TokenBuffer` never touches donated storage.
+
+        The call runs under the ``kairos.engine.compile`` span, and adds
+        to the ``compiles`` / ``compile_s`` counters, when its argument
+        shapes are new to the runner; under ``kairos.engine.dispatch``
+        otherwise."""
         self.n_dispatches += 1
         # numpy arrays go straight to the jitted call: the C++ dispatch
         # path converts them far cheaper than 12 python-level jnp.asarray
         # round-trips (measured ~1.7 ms/iteration at smoke scale)
-        nxt, self.pool = self._fused_fn(
-            self.params, self.pool, batch.tokens_p, batch.positions_p,
-            batch.tables_p, batch.tokens_d, batch.positions_d,
-            batch.tables_d, batch.write_slots, batch.sample_rows,
-            batch.cow_src, batch.cow_dst)
+        args = (batch.tokens_p, batch.positions_p, batch.tables_p,
+                batch.tokens_d, batch.positions_d, batch.tables_d,
+                batch.write_slots, batch.sample_rows, batch.cow_src,
+                batch.cow_dst)
+        shapes = tuple(a.shape for a in args)
+        new = shapes not in self._issued
+        t0 = time.perf_counter()
+        with span("kairos.engine.compile" if new else "kairos.engine.dispatch"):
+            nxt, self.pool = self._fused_fn(self.params, self.pool, *args)
+        if new:
+            self._issued.add(shapes)
+            self.metrics.inc("compiles")
+            self.metrics.inc("compile_s", time.perf_counter() - t0)
         return nxt
 
     def _build_fused(self):
@@ -652,8 +675,8 @@ class PagedModelRunner:
         c._pool_pspec = self._pool_pspec
         c._param_specs = self._param_specs
         c.pool = c._new_pool()
-        c.metrics = MetricsRegistry()
-        c.n_dispatches = 0
+        c._new_metrics()
+        c._issued = self._issued
         c._decode_fn = self._decode_fn
         c._prefill_fn = self._prefill_fn
         c._suffix_fn = self._suffix_fn
@@ -693,20 +716,28 @@ class TokenBuffer:
     — a buffer XLA allocates fresh (outputs alias only donated inputs,
     and the pool's shape can't alias a token vector), so a deferred
     ``host()`` read is safe no matter how many further iterations have
-    donated and overwritten the pool in the meantime."""
+    donated and overwritten the pool in the meantime.
 
-    __slots__ = ("_dev", "_host")
+    The first ``host()`` runs under the ``kairos.engine.sync`` span and
+    then calls ``on_host`` once: the moment the tokens are on the host."""
 
-    def __init__(self, dev):
+    __slots__ = ("_dev", "_host", "_on_host")
+
+    def __init__(self, dev, on_host: Optional[Callable[[], None]] = None):
         self._dev = dev
         self._host: Optional[np.ndarray] = None
+        self._on_host = on_host
 
     def host(self) -> np.ndarray:
         if self._host is None:
             dev = self._dev               # local ref: a concurrent host()
             if dev is not None:           # call can never hand us None
-                self._host = np.asarray(dev)
+                with span("kairos.engine.sync"):
+                    self._host = np.asarray(dev)
                 self._dev = None          # release the device buffer early
+                on_host, self._on_host = self._on_host, None
+                if on_host is not None:
+                    on_host()
         return self._host
 
 
@@ -714,9 +745,8 @@ class TokenRef:
     """One row of a :class:`TokenBuffer`: a not-yet-synced token id.
 
     Converts to ``int`` on demand (``__int__``/``__index__``), so host
-    code that stores pending tokens — the engine's next-token map, a
-    request's ``output_tokens`` while it is still running — never blocks
-    on the device until the value is observed.  Comparison syncs too:
+    code that stores pending tokens — the engine's next-token map — never
+    blocks on the device until the value is observed.  Comparison syncs too:
     equality against a plain int is value equality."""
 
     __slots__ = ("buf", "row")
@@ -799,8 +829,9 @@ class LLMEngine:
         # fault plane (serving/faults.py): wired by the cluster; when set,
         # every composed iteration consults the injector mid-dispatch
         self.faults = None
-        # wall seconds of the last dispatch+sync, written by the stepping
-        # thread — recovery's step-deadline check reads it post-collect
+        # wall seconds of the newest step whose tokens reached the host,
+        # from the start of its dispatch_iteration: written by the thread
+        # that synced them, read by recovery's step-deadline check
         self.last_step_wall = 0.0
         self.sched = BatchScheduler(
             self.bm, policy=policy, prefix_cache=self.prefix_cache,
@@ -929,7 +960,9 @@ class LLMEngine:
         single dispatch to defer; the iteration executes synchronously
         here and ``collect`` just hands back its finishers."""
         assert not self.has_pending, "collect() the previous iteration first"
-        plan = self.sched.plan(self.clock())
+        t0 = time.monotonic()
+        with span("kairos.sched.plan"):
+            plan = self.sched.plan(self.clock())
         if plan is None:
             return False
         if self.faults is not None:
@@ -946,12 +979,35 @@ class LLMEngine:
                 raise InstanceCrashed(self.instance_id, eff.crash.step)
         if not self.fused_iteration:
             self._pending_finished = self._execute_per_chunk(plan)
+            self.last_step_wall = time.monotonic() - t0
             return True
-        batch = flatten_plan(plan, self.bm, self._next_tok)
+        with span("kairos.sched.flatten"):
+            batch = flatten_plan(plan, self.bm, self._next_tok)
         self.runner.metrics.observe("iteration_tokens", batch.n_tokens)
         self.runner.metrics.observe("batch_occupancy", len(batch.segments))
-        self._pending = (batch, TokenBuffer(self.runner.run_iteration(batch)))
+        # a final prefill chunk's argmax IS the call's first token
+        firsts = [(seg.req, seg.req.n_preemptions) for seg in batch.segments
+                  if seg.kind == "prefill" and seg.emits_token]
+        toks = TokenBuffer(self.runner.run_iteration(batch),
+                           functools.partial(self._on_host, t0, firsts))
+        self._pending = (batch, toks)
         return True
+
+    def _on_host(self, t0: float, firsts: List[Tuple[Request, int]]):
+        """A step's tokens reached the host: its wall time runs from the
+        start of its :meth:`dispatch_iteration` to now, and the first
+        tokens it computed are stamped now (TTFT), unless the call was
+        preempted since and its token dropped."""
+        self.last_step_wall = time.monotonic() - t0
+        now = self.clock()
+        for r, n_preempted in firsts:
+            if r.first_token_time >= 0 or r.n_preemptions != n_preempted:
+                continue
+            r.first_token_time = now
+            if self.tracer.enabled:
+                self.tracer.emit("first-token", req_id=r.req_id,
+                                 instance_id=self.instance_id,
+                                 agent=r.agent_name, msg_id=r.msg_id, ts=now)
 
     def sync(self):
         """Block until the in-flight iteration's next-token ids are
@@ -966,52 +1022,44 @@ class LLMEngine:
         """Book the dispatched iteration's results; returns finished
         requests.  All bookkeeping is host-side metadata: new tokens are
         recorded as :class:`TokenRef`s, so nothing blocks on the device
-        unless a request finished (its output materializes), EOS checking
-        demands token values, or ``force_sync`` asks for the legacy
-        blocking behaviour."""
-        if self._pending_finished is not None:
-            finished, self._pending_finished = self._pending_finished, None
+        unless EOS checking demands token values or ``force_sync`` asks
+        for the legacy blocking behaviour.  A decode row books the token
+        it was fed, which its flatten already brought to the host; a
+        first token is stamped when its buffer syncs (:meth:`_on_host`)."""
+        with span("kairos.engine.collect"):
+            if self._pending_finished is not None:
+                finished, self._pending_finished = self._pending_finished, None
+                return finished
+            if self._pending is None:
+                return []
+            (batch, toks), self._pending = self._pending, None
+            if force_sync or self.eos_token >= 0:
+                toks.host()
+            finished = []
+            now = self.clock()
+            traced = self.tracer.enabled
+            for j, seg in enumerate(batch.segments):
+                r = seg.req
+                if seg.kind == "prefill":
+                    if seg.emits_token:
+                        self._next_tok[r.req_id] = TokenRef(toks, j)
+                    continue
+                r.output_tokens.append(int(self._next_tok[r.req_id]))
+                r.output_len += 1
+                self._next_tok[r.req_id] = TokenRef(toks, j)
+                if traced:
+                    self.tracer.emit("decode", req_id=r.req_id,
+                                     instance_id=self.instance_id,
+                                     agent=r.agent_name, msg_id=r.msg_id,
+                                     ts=now)
+                done = (r.output_len >= r.max_new_tokens
+                        or (self.eos_token >= 0
+                            and int(toks.host()[j]) == self.eos_token))
+                if done:
+                    self.sched.finish(r, self.clock())
+                    self._next_tok.pop(r.req_id, None)
+                    finished.append(r)
             return finished
-        if self._pending is None:
-            return []
-        (batch, toks), self._pending = self._pending, None
-        if force_sync or self.eos_token >= 0:
-            toks.host()
-        finished = []
-        now = self.clock()
-        traced = self.tracer.enabled
-        for j, seg in enumerate(batch.segments):
-            r = seg.req
-            if seg.kind == "prefill":
-                if seg.emits_token:
-                    self._next_tok[r.req_id] = TokenRef(toks, j)
-                    # the final chunk's argmax IS the first generated
-                    # token — TTFT is timed at its collection
-                    if r.first_token_time < 0:
-                        r.first_token_time = now
-                    if traced:
-                        self.tracer.emit("first-token", req_id=r.req_id,
-                                         instance_id=self.instance_id,
-                                         agent=r.agent_name,
-                                         msg_id=r.msg_id, ts=now)
-                continue
-            fed = self._next_tok[r.req_id]
-            r.output_tokens.append(fed)
-            r.output_len += 1
-            self._next_tok[r.req_id] = TokenRef(toks, j)
-            if traced:
-                self.tracer.emit("decode", req_id=r.req_id,
-                                 instance_id=self.instance_id,
-                                 agent=r.agent_name, msg_id=r.msg_id, ts=now)
-            done = (r.output_len >= r.max_new_tokens
-                    or (self.eos_token >= 0
-                        and int(toks.host()[j]) == self.eos_token))
-            if done:
-                r.output_tokens[:] = [int(t) for t in r.output_tokens]
-                self.sched.finish(r, self.clock())
-                self._next_tok.pop(r.req_id, None)
-                finished.append(r)
-        return finished
 
     def _execute_per_chunk(self, plan) -> List[Request]:
         """Legacy differential-testing path: one jitted dispatch per

@@ -17,9 +17,15 @@ Covered here:
 * metrics registry snapshots + engine counter consolidation
   (``runner.n_dispatches`` is registry-backed);
 * orchestrator EMA: ``expected_exec_time`` feeds from measured spans
-  when traced, static profiler fallback otherwise.
+  when traced, static profiler fallback otherwise;
+* the serving step's profiler spans (``obs.span``): every ``kairos.*``
+  name reaches the ``.xplane.pb`` of a drain, spans nest on their
+  thread, the runner's compile counter, and a first token stamped when
+  it reaches the host.
 """
 import json
+import tempfile
+from pathlib import Path
 
 import jax
 import numpy as np
@@ -396,3 +402,151 @@ def test_orchestrator_expected_exec_time_feeds_from_measured_spans():
     # EMA moves toward a faster second sample
     orch.on_completion(_rec("qa", 0.0, 1.0, 3.0, 5))
     assert orch.expected_exec_time("qa") < t_traced
+
+
+# =============================================================================
+# the serving step's profiler spans
+# =============================================================================
+
+SPAN_NAMES = ("kairos.step", "kairos.ctrl.tick", "kairos.sched.plan",
+              "kairos.sched.flatten", "kairos.engine.dispatch",
+              "kairos.engine.compile", "kairos.engine.collect",
+              "kairos.engine.sync")
+
+
+def _program_spans(trace_dir):
+    """(name, start_ns, end_ns, line) of every ``kairos.*`` host span in
+    the profile written under ``trace_dir``."""
+    from jax.profiler import ProfileData
+    xplane = sorted(Path(trace_dir).rglob("*.xplane.pb"))[-1]
+    out = []
+    for plane in ProfileData.from_file(str(xplane)).planes:
+        for i, line in enumerate(plane.lines):
+            for ev in line.events:
+                if ev.name.startswith("kairos."):
+                    out.append((ev.name, ev.start_ns,
+                                ev.start_ns + ev.duration_ns,
+                                f"{plane.name}#{i}"))
+    return sorted(out, key=lambda e: (e[1], -e[2]))
+
+
+def _one_engine_cluster(model_and_params, clock=None):
+    model, params = model_and_params
+    reset_request_ids()
+    runner = PagedModelRunner(model, params, num_blocks=64, block_size=8,
+                              max_batch=4)
+    kw = {} if clock is None else {"clock": clock}
+    eng = LLMEngine(runner, max_batch=4, prefill_chunk_tokens=16, **kw)
+    orch = Orchestrator(hardware=HardwareProfile(
+        decode_tok_per_s=20.0, kv_capacity_tokens=64 * 8))
+    return ServingCluster([eng], orch, **kw), eng
+
+
+def test_drain_under_the_profiler_writes_every_span(model_and_params):
+    cluster, _ = _one_engine_cluster(model_and_params)
+    for r in _reqs(n=3, max_new=4):
+        cluster.submit(r)
+    with tempfile.TemporaryDirectory() as d:
+        jax.profiler.start_trace(d)
+        try:
+            done = cluster.drain()
+        finally:
+            jax.profiler.stop_trace()
+        spans = _program_spans(d)
+    cluster.close()
+    assert len(done) == 3
+    assert set(SPAN_NAMES) <= {s[0] for s in spans}
+    steps = [s for s in spans if s[0] == "kairos.step"]
+    assert len({s[3] for s in steps}) == 1       # one engine: one thread
+    # every span nests on its thread: inside its parent, and the self
+    # times (duration less the direct children) add up to each step
+    self_ns = {}
+    stack = []
+    for name, a, b, line in spans:
+        while stack and stack[-1][2] <= a:
+            stack.pop()
+        if stack:
+            assert b <= stack[-1][2] and line == stack[-1][3], (name, stack[-1])
+            self_ns[stack[-1]] -= b - a
+        parent = stack[-1][0] if stack else None
+        if name == "kairos.engine.sync" and parent != "kairos.engine.collect":
+            assert parent == "kairos.sched.flatten", parent
+        if name != "kairos.step":
+            assert parent is not None, name
+        s = (name, a, b, line)
+        self_ns[s] = b - a
+        stack.append(s)
+    syncs = [s for s in spans if s[0] == "kairos.engine.sync"]
+    flats = [s for s in spans if s[0] == "kairos.sched.flatten"]
+    assert any(f[1] <= s[1] and s[2] <= f[2] for s in syncs for f in flats)
+    for st in steps:
+        inside = [k for k in self_ns if st[1] <= k[1] and k[2] <= st[2]]
+        assert sum(self_ns[k] for k in inside) == st[2] - st[1]
+
+
+def test_runner_counts_a_compile_once_per_new_shape(model_and_params):
+    """``compiles`` rises by one exactly when the fused step's compiled
+    specializations grow (a padded shape new to the runner), and by
+    zero on a shape it has issued before."""
+    cluster, eng = _one_engine_cluster(model_and_params)
+    runner = eng.runner
+    for r in _reqs(n=5, max_new=6):
+        cluster.submit(r)
+    rises = []
+    while cluster.has_work:
+        c0 = runner.metrics_snapshot()["compiles"]
+        k0 = runner._fused_fn._cache_size()
+        cluster.step()
+        snap = runner.metrics_snapshot()
+        rises.append((snap["compiles"] - c0,
+                      runner._fused_fn._cache_size() - k0))
+    cluster.close()
+    assert all(a == b for a, b in rises), rises
+    assert {a for a, _ in rises} == {0, 1}       # new shapes and repeats
+    snap = runner.metrics_snapshot()
+    assert snap["compiles"] == runner._fused_fn._cache_size()
+    assert snap["compile_s"] > 0
+    # a clone shares the compiled step, so it counts no compile for it
+    assert runner.clone().metrics_snapshot()["compiles"] == 0
+
+
+def test_first_token_is_stamped_when_it_reaches_the_host(model_and_params,
+                                                          monkeypatch):
+    """With a clock that steps at every reading, each call's
+    ``first_token_time`` is no earlier than the reading taken when the
+    buffer holding its first token synced, and later than the collect
+    of the step that computed it."""
+    from repro.serving import engine as engine_mod
+    ticks = iter(range(1, 1_000_000))
+    now = {"t": 0.0}
+
+    def clock():
+        now["t"] = float(next(ticks))
+        return now["t"]
+
+    synced_at = {}
+    host = engine_mod.TokenBuffer.host
+
+    def recording_host(buf):
+        if id(buf) not in synced_at:
+            synced_at[id(buf)] = clock()
+        return host(buf)
+
+    monkeypatch.setattr(engine_mod.TokenBuffer, "host", recording_host)
+    cluster, eng = _one_engine_cluster(model_and_params, clock=clock)
+    first_buf, collected_at = {}, {}
+    for r in _reqs(n=3, max_new=4):
+        cluster.submit(r)
+    done = []
+    while cluster.has_work:
+        done.extend(cluster.step())
+        for rid, tok in eng._next_tok.items():
+            if isinstance(tok, engine_mod.TokenRef) and rid not in first_buf:
+                first_buf[rid] = tok.buf
+                collected_at[rid] = now["t"]
+    cluster.close()
+    assert len(done) == 3
+    for r in done:
+        assert r.first_token_time >= synced_at[id(first_buf[r.req_id])]
+        assert r.first_token_time > collected_at[r.req_id]
+        assert r.first_token_time <= r.finish_time
