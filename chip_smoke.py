@@ -172,24 +172,27 @@ def phase_kernels(cfg, seed: int, clock: CompileClock):
         name = jnp.dtype(dtype).name
         atol, rtol = TOL[name]
         key = jax.random.PRNGKey(seed)
-        kq, kk, kv_, kr = jax.random.split(key, 4)
-        k_pool = jax.random.normal(kk, (n_pages, kv, bs, hd), dtype)
-        v_pool = jax.random.normal(kv_, (n_pages, kv, bs, hd), dtype)
+        kq, kk, kr = jax.random.split(key, 3)
+        # the kernels read layer 1 of a two-layer pool; the oracles take
+        # that layer's pages
+        pool = jax.random.normal(kk, (2, 2, n_pages, kv, bs, hd), dtype)
+        k_pool, v_pool = pool[1, 0], pool[1, 1]
         q = jax.random.normal(kq, (8, kv, g, hd), dtype)
         qr = jax.random.normal(kr, (2, 512, kv, g, hd), dtype)
         cases = [
             ("paged_attention", paged_attention, ref.paged_attention_ref,
-             (q, k_pool, v_pool, jnp.asarray(tables), jnp.asarray(ctx))),
+             q, (jnp.asarray(tables), jnp.asarray(ctx))),
             ("ragged_segment_attention", ragged_segment_attention,
              ref.ragged_segment_attention_ref,
-             (qr, k_pool, v_pool, jnp.asarray(tables[:2, :96]),
-              jnp.asarray(positions))),
+             qr, (jnp.asarray(tables[:2, :96]), jnp.asarray(positions))),
         ]
         with jax.default_matmul_precision("highest" if name == "float32"
                                           else "default"):
-            for kname, kernel, oracle, args in cases:
-                out = np.asarray(kernel(*args), np.float32)
-                want = np.asarray(oracle(*args), np.float32)
+            for kname, kernel, oracle, qq, rest in cases:
+                out = np.asarray(kernel(qq, pool, jnp.int32(1), *rest),
+                                 np.float32)
+                want = np.asarray(oracle(qq, k_pool, v_pool, *rest),
+                                  np.float32)
                 err = np.abs(out - want)
                 bound = atol + rtol * np.abs(want)
                 ok = bool(np.isfinite(out).all() and (err <= bound).all())

@@ -24,24 +24,26 @@ def default_backend() -> str:
 
 
 @functools.partial(jax.jit, static_argnames=("backend",))
-def paged_attention(q, k_pool, v_pool, block_tables, context_lens, backend: str = "ref"):
-    """Decode attention over a paged KV pool.  See kernels/ref.py for shapes."""
-    if backend == "pallas":
-        return _paged_pallas(q, k_pool, v_pool, block_tables, context_lens)
-    if backend == "interpret":
-        return _paged_pallas(q, k_pool, v_pool, block_tables, context_lens, interpret=True)
+def paged_attention(q, pool, layer, block_tables, context_lens, backend: str = "ref"):
+    """Decode attention over layer ``layer`` of the whole paged KV pool
+    (L, 2, N, KV, bs, hd).  See kernels/ref.py for the other shapes."""
+    if backend in ("pallas", "interpret"):
+        return _paged_pallas(q, pool, layer, block_tables, context_lens,
+                             interpret=backend == "interpret")
     if backend != "ref":
         raise ValueError(f"unknown paged attention backend {backend!r}")
-    return _ref.paged_attention_ref(q, k_pool, v_pool, block_tables, context_lens)
+    return _ref.paged_attention_ref(q, pool[layer, 0], pool[layer, 1],
+                                    block_tables, context_lens)
 
 
 @functools.partial(jax.jit, static_argnames=("backend",))
-def ragged_segment_attention(q, k_pool, v_pool, block_tables, positions,
+def ragged_segment_attention(q, pool, layer, block_tables, positions,
                              backend: str = "ref"):
-    """Segment-blocked causal attention over a paged pool for the prefill
-    part of a fused :class:`~repro.serving.batch_scheduler.IterationBatch`
-    — every chunk's tokens tiled to (S, L).  See ``kernels/ref.py`` for
-    shapes and mask semantics.
+    """Segment-blocked causal attention over layer ``layer`` of the whole
+    paged pool (L, 2, N, KV, bs, hd) for the prefill part of a fused
+    :class:`~repro.serving.batch_scheduler.IterationBatch` — every chunk's
+    tokens tiled to (S, L).  See ``kernels/ref.py`` for the other shapes
+    and mask semantics.
 
     Backends
     --------
@@ -64,7 +66,7 @@ def ragged_segment_attention(q, k_pool, v_pool, block_tables, positions,
     if q.size == 0:        # absent prefill part (decode-only iteration):
         return q           # every backend must no-op, not trace 0 rows
     if backend in ("pallas", "interpret"):
-        return _ragged_pallas(q, k_pool, v_pool, block_tables, positions,
+        return _ragged_pallas(q, pool, layer, block_tables, positions,
                               interpret=backend == "interpret")
     if backend in ("flat", "flat_interpret", "flat_ref"):
         s, lq, kv, g, hd = q.shape
@@ -72,10 +74,10 @@ def ragged_segment_attention(q, k_pool, v_pool, block_tables, positions,
         flat_bt = jnp.repeat(block_tables, lq, axis=0)
         flat_cl = positions.reshape(-1) + 1
         if backend == "flat_ref":
-            out = _ref.paged_attention_ref(flat_q, k_pool, v_pool,
-                                           flat_bt, flat_cl)
+            out = _ref.paged_attention_ref(flat_q, pool[layer, 0],
+                                           pool[layer, 1], flat_bt, flat_cl)
         else:
-            out = _paged_pallas(flat_q, k_pool, v_pool, flat_bt, flat_cl,
+            out = _paged_pallas(flat_q, pool, layer, flat_bt, flat_cl,
                                 interpret=backend == "flat_interpret")
         return out.reshape(s, lq, kv, g, hd)
     if backend != "ref":
@@ -83,4 +85,4 @@ def ragged_segment_attention(q, k_pool, v_pool, block_tables, positions,
         # into a device hot loop (token-identical, so nothing else catches it)
         raise ValueError(f"unknown ragged attention backend {backend!r}")
     return _ref.ragged_segment_attention_ref(
-        q, k_pool, v_pool, block_tables, positions)
+        q, pool[layer, 0], pool[layer, 1], block_tables, positions)

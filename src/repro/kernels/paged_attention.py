@@ -8,14 +8,18 @@ HBM->VMEM page copies double-buffered while the MXU reduces the previous
 page.  Accumulation is the standard running-max/denominator (flash)
 reduction in fp32 VMEM scratch.
 
-Grid: (B, KV_heads, num_pages).  The pool is laid out head-major within
-a page, (N, KV, bs, hd), so one (kv_head, page) tile is a contiguous
-(block_size, head_dim) slab: the last two dims of every k/v block are
-(bs, hd), which is what Mosaic's TPU lowering asks of a block (second-
-minor divisible by 8, minor by 128, or equal to the array's dims).  A
-token-major page (bs, KV, hd) would make the block's second-minor dim a
-single KV head out of KV, which the TPU compiler refuses.  A bf16 page
-fills one native (16, 128) tile at bs=16; bs=8 compiles as well.
+Grid: (B, KV_heads, num_pages).  The kernel reads the serving engine's
+whole pool, (L, 2, N, KV, bs, hd), at a traced ``layer``: the layer is
+scalar-prefetched beside the tables and the K and V index maps point at
+``(layer, 0, page, head)`` and ``(layer, 1, page, head)``, so the layer
+scan hands every layer the same pool buffer and no per-layer slice is
+ever materialized.  Pages are head-major, so one (kv_head, page) tile is
+a contiguous (block_size, head_dim) slab: the last two dims of every k/v
+block are (bs, hd), which is what Mosaic's TPU lowering asks of a block
+(second-minor divisible by 8, minor by 128, or equal to the array's
+dims).  A token-major page (bs, KV, hd) would make the block's second-
+minor dim a single KV head out of KV, which the TPU compiler refuses.  A
+bf16 page fills one native (16, 128) tile at bs=16; bs=8 compiles as well.
 """
 from __future__ import annotations
 
@@ -31,9 +35,10 @@ NEG_INF = -1e30
 
 def _paged_attn_kernel(block_tables_ref,   # (B, nb) SMEM (scalar prefetch)
                        context_lens_ref,   # (B,)   SMEM (scalar prefetch)
+                       layer_ref,          # (1,)   SMEM (scalar prefetch)
                        q_ref,              # (1, 1, G, hd) VMEM
-                       k_ref,              # (1, 1, bs, hd) VMEM (gathered page)
-                       v_ref,              # (1, 1, bs, hd) VMEM
+                       k_ref,              # (1, 1, 1, 1, bs, hd) VMEM (gathered page)
+                       v_ref,              # (1, 1, 1, 1, bs, hd) VMEM
                        o_ref,              # (1, 1, G, hd) VMEM
                        acc_ref,            # (G, hd) f32 scratch
                        m_ref,              # (G, 1) f32 scratch
@@ -57,8 +62,8 @@ def _paged_attn_kernel(block_tables_ref,   # (B, nb) SMEM (scalar prefetch)
     @pl.when(n * bs < cl)
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32)                  # (G, hd)
-        k = k_ref[0, 0].astype(jnp.float32)                  # (bs, hd)
-        v = v_ref[0, 0].astype(jnp.float32)
+        k = k_ref[0, 0, 0, 0].astype(jnp.float32)            # (bs, hd)
+        v = v_ref[0, 0, 0, 0].astype(jnp.float32)
 
         scores = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
@@ -83,16 +88,17 @@ def _paged_attn_kernel(block_tables_ref,   # (B, nb) SMEM (scalar prefetch)
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def paged_attention(q: jnp.ndarray,
-                    k_pool: jnp.ndarray,
-                    v_pool: jnp.ndarray,
+                    pool: jnp.ndarray,
+                    layer: jnp.ndarray,
                     block_tables: jnp.ndarray,
                     context_lens: jnp.ndarray,
                     interpret: bool = False) -> jnp.ndarray:
-    """q (B,KV,G,hd); pools (N, KV, bs, hd); tables (B, nb); lens (B,)."""
+    """q (B,KV,G,hd); pool (L, 2, N, KV, bs, hd), K at [layer, 0] and V at
+    [layer, 1]; layer an int32 scalar; tables (B, nb); lens (B,)."""
     b, kv, g, hd = q.shape
     if q.size == 0:        # absent decode part (prefill-only iteration)
         return q
-    bs = k_pool.shape[2]
+    bs = pool.shape[4]
     nb = block_tables.shape[1]
     scale = hd ** -0.5
 
@@ -108,21 +114,31 @@ def paged_attention(q: jnp.ndarray,
     # maximum makes the clamp total: cl=0 (every in-repo caller clamps
     # cl>=1, but this is a public entry point) pins page 0 instead of
     # feeding a negative SMEM index to the table
-    def page_map(bb, h, n, bt, cl):
-        return (bt[bb, jnp.minimum(n, jnp.maximum(cl[bb] - 1, 0) // bs)],
-                h, 0, 0)
+    def page(bb, n, bt, cl):
+        return bt[bb, jnp.minimum(n, jnp.maximum(cl[bb] - 1, 0) // bs)]
 
+    def k_map(bb, h, n, bt, cl, lay):
+        return (lay[0], 0, page(bb, n, bt, cl), h, 0, 0)
+
+    def v_map(bb, h, n, bt, cl, lay):
+        return (lay[0], 1, page(bb, n, bt, cl), h, 0, 0)
+
+    def row_map(bb, h, n, bt, cl, lay):
+        return (bb, h, 0, 0)
+
+    layer = jnp.asarray(layer, jnp.int32).reshape(1)
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=3,
             grid=grid,
             in_specs=[
-                pl.BlockSpec((1, 1, g, hd), lambda bb, h, n, bt, cl: (bb, h, 0, 0)),
-                pl.BlockSpec((1, 1, bs, hd), page_map),
-                pl.BlockSpec((1, 1, bs, hd), page_map),
+                pl.BlockSpec((1, 1, g, hd), row_map),
+                # the pool goes in twice, once per index map
+                pl.BlockSpec((1, 1, 1, 1, bs, hd), k_map),
+                pl.BlockSpec((1, 1, 1, 1, bs, hd), v_map),
             ],
-            out_specs=pl.BlockSpec((1, 1, g, hd), lambda bb, h, n, bt, cl: (bb, h, 0, 0)),
+            out_specs=pl.BlockSpec((1, 1, g, hd), row_map),
             scratch_shapes=[
                 pltpu.VMEM((g, hd), jnp.float32),
                 pltpu.VMEM((g, 1), jnp.float32),
@@ -131,4 +147,4 @@ def paged_attention(q: jnp.ndarray,
         ),
         out_shape=jax.ShapeDtypeStruct((b, kv, g, hd), q.dtype),
         interpret=interpret,
-    )(block_tables, context_lens, q, k_pool, v_pool)
+    )(block_tables, context_lens, layer, q, pool, pool)

@@ -19,12 +19,18 @@ This kernel is the native formulation:
   query slab of that head: the wrapper lays q out as (S, KV, L·G, hd),
   so each step is one (L·G, bs) MXU product instead of L separate
   (G, bs) steps;
-* **head-major pages** — the pool is (N, KV, bs, hd), so a (kv_head,
-  page) k/v block is a dense (bs, hd) tile, the block shape the TPU
-  compiler accepts;
+* **head-major pages** — pages are (KV, bs, hd), so a (kv_head, page)
+  k/v block is a dense (bs, hd) tile, the block shape the TPU compiler
+  accepts;
+* **the whole pool at a traced layer** — the kernel takes the serving
+  engine's pool (L, 2, N, KV, bs, hd) and a ``layer`` scalar, and its K
+  and V index maps point at ``(layer, 0, page, head)`` and ``(layer, 1,
+  page, head)``: the layer scan carries one pool buffer and never
+  slices a layer out of it;
 * **scalar-prefetched block tables** — like the decode kernel, the
-  per-segment table and page bound live in SMEM and feed the BlockSpec
-  index maps, so Pallas double-buffers the HBM→VMEM page copies;
+  per-segment table, page bound and layer live in SMEM and feed the
+  BlockSpec index maps, so Pallas double-buffers the HBM→VMEM page
+  copies;
 * **per-segment page bounds** — a segment only *visits* pages up to
   ``max(positions) // bs``: beyond its bound the k/v index maps clamp to
   the bound page, and consecutive grid steps with an unchanged block
@@ -53,10 +59,11 @@ NEG_INF = -1e30
 
 def _ragged_attn_kernel(block_tables_ref,   # (S, nb) SMEM (scalar prefetch)
                         page_bounds_ref,    # (S,)    SMEM (scalar prefetch)
+                        layer_ref,          # (1,)    SMEM (scalar prefetch)
                         q_ref,              # (1, 1, L*G, hd) VMEM
                         pos_ref,            # (1, L*G, 1) VMEM
-                        k_ref,              # (1, 1, bs, hd) VMEM (gathered page)
-                        v_ref,              # (1, 1, bs, hd) VMEM
+                        k_ref,              # (1, 1, 1, 1, bs, hd) VMEM (gathered page)
+                        v_ref,              # (1, 1, 1, 1, bs, hd) VMEM
                         o_ref,              # (1, 1, L*G, hd) VMEM
                         acc_ref,            # (L*G, hd) f32 scratch
                         m_ref,              # (L*G, 1) f32 scratch
@@ -76,8 +83,8 @@ def _ragged_attn_kernel(block_tables_ref,   # (S, nb) SMEM (scalar prefetch)
     @pl.when(n <= page_bounds_ref[s])
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32)                   # (L*G, hd)
-        k = k_ref[0, 0].astype(jnp.float32)                   # (bs, hd)
-        v = v_ref[0, 0].astype(jnp.float32)
+        k = k_ref[0, 0, 0, 0].astype(jnp.float32)             # (bs, hd)
+        v = v_ref[0, 0, 0, 0].astype(jnp.float32)
 
         scores = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
@@ -105,18 +112,19 @@ def _ragged_attn_kernel(block_tables_ref,   # (S, nb) SMEM (scalar prefetch)
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def ragged_segment_attention(q: jnp.ndarray,
-                             k_pool: jnp.ndarray,
-                             v_pool: jnp.ndarray,
+                             pool: jnp.ndarray,
+                             layer: jnp.ndarray,
                              block_tables: jnp.ndarray,
                              positions: jnp.ndarray,
                              interpret: bool = False) -> jnp.ndarray:
-    """q (S, L, KV, G, hd); pools (N, KV, bs, hd); tables (S, nb);
+    """q (S, L, KV, G, hd); pool (L_layers, 2, N, KV, bs, hd), K at
+    [layer, 0] and V at [layer, 1]; layer an int32 scalar; tables (S, nb);
     positions (S, L) absolute position per query token.  Returns
     (S, L, KV, G, hd).  See ``kernels/ref.py`` for mask semantics."""
     s, lq, kv, g, hd = q.shape
     if q.size == 0:        # absent prefill part (decode-only iteration)
         return q
-    bs = k_pool.shape[2]
+    bs = pool.shape[4]
     nb = block_tables.shape[1]
     scale = hd ** -0.5
     # last page each segment can attend: max position // bs (padding rows
@@ -132,25 +140,30 @@ def ragged_segment_attention(q: jnp.ndarray,
     kernel = functools.partial(_ragged_attn_kernel, bs=bs, nb=nb, scale=scale)
     grid = (s, kv, nb)
 
-    def page_map(ss, h, n, bt, bounds):
-        return (bt[ss, jnp.minimum(n, bounds[ss])], h, 0, 0)
+    def k_map(ss, h, n, bt, bounds, lay):
+        return (lay[0], 0, bt[ss, jnp.minimum(n, bounds[ss])], h, 0, 0)
 
+    def v_map(ss, h, n, bt, bounds, lay):
+        return (lay[0], 1, bt[ss, jnp.minimum(n, bounds[ss])], h, 0, 0)
+
+    def slab_map(ss, h, n, bt, bounds, lay):
+        return (ss, h, 0, 0)
+
+    layer = jnp.asarray(layer, jnp.int32).reshape(1)
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=3,
             grid=grid,
             in_specs=[
-                pl.BlockSpec((1, 1, rows, hd),
-                             lambda ss, h, n, bt, bounds: (ss, h, 0, 0)),
+                pl.BlockSpec((1, 1, rows, hd), slab_map),
                 pl.BlockSpec((1, rows, 1),
-                             lambda ss, h, n, bt, bounds: (ss, 0, 0)),
-                pl.BlockSpec((1, 1, bs, hd), page_map),
-                pl.BlockSpec((1, 1, bs, hd), page_map),
+                             lambda ss, h, n, bt, bounds, lay: (ss, 0, 0)),
+                # the pool goes in twice, once per index map
+                pl.BlockSpec((1, 1, 1, 1, bs, hd), k_map),
+                pl.BlockSpec((1, 1, 1, 1, bs, hd), v_map),
             ],
-            out_specs=pl.BlockSpec(
-                (1, 1, rows, hd),
-                lambda ss, h, n, bt, bounds: (ss, h, 0, 0)),
+            out_specs=pl.BlockSpec((1, 1, rows, hd), slab_map),
             scratch_shapes=[
                 pltpu.VMEM((rows, hd), jnp.float32),
                 pltpu.VMEM((rows, 1), jnp.float32),
@@ -159,5 +172,5 @@ def ragged_segment_attention(q: jnp.ndarray,
         ),
         out_shape=jax.ShapeDtypeStruct((s, kv, rows, hd), q.dtype),
         interpret=interpret,
-    )(block_tables, page_bounds, q_t, pos_rows, k_pool, v_pool)
+    )(block_tables, page_bounds, layer, q_t, pos_rows, pool, pool)
     return out.reshape(s, kv, lq, g, hd).transpose(0, 2, 1, 3, 4)

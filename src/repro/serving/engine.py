@@ -95,11 +95,19 @@ def _layer_finish(xx, o, lp, cfg, axis: Optional[str] = None):
     return xx + f
 
 
-def _write_tokens(pages, slots, x, bs: int):
-    """Write token rows ``x`` (T, n_kv, hd) into one layer's pages
-    (N, n_kv, bs, hd) at flat slots ``block * bs + offset``; a slot past
-    the pool (``N * bs``) drops its row."""
-    return pages.at[slots // bs, :, slots % bs].set(x, mode="drop")
+def _write_tokens(pool, layer, slots, k, v, bs: int):
+    """Write token rows ``k`` and ``v`` (T, n_kv, hd) into layer ``layer``
+    of the whole pool (L, 2, N, n_kv, bs, hd) at flat slots
+    ``block * bs + offset``; a slot past the pool (``N * bs``) drops its
+    row.  Every (token, KV head) pair is indexed on its own, so each
+    scatter window is one ``hd`` row, the pool's minor dim: a window of
+    (n_kv, hd) would make XLA's layout assignment swap the KV-head and
+    in-block dims of the carried pool, and convert the whole pool to and
+    from the Pallas kernels' default layout around every step."""
+    blk, off = (slots // bs)[:, None], (slots % bs)[:, None]
+    heads = jnp.arange(k.shape[1])[None, :]
+    pool = pool.at[layer, 0, blk, heads, off].set(k, mode="drop")
+    return pool.at[layer, 1, blk, heads, off].set(v, mode="drop")
 
 
 class PagedModelRunner:
@@ -113,11 +121,18 @@ class PagedModelRunner:
     **In-place pool semantics** (``donate_pool``, default on): every
     jitted step function *donates* the pool argument, so XLA writes the
     updated pool into the very buffer it read — one resident pool buffer
-    per runner for the lifetime of the process, zero pool-copy bytes per
-    dispatch.  Without donation each dispatch materializes a second
-    full-size pool buffer just to change a few KV rows (and the pre-PR5
-    out-of-jit ``at[].set`` writes in ``prefill``/``copy_block`` copied
-    the whole pool *again* to write one block).  The donation invariant:
+    per runner for the lifetime of the process.  Donation holds the
+    buffer at the jit boundary only; inside the step, the decode and
+    fused steps' layer scan *carries* the whole pool (never a per-layer
+    slice as a scan input or output), each layer scatters its fresh K/V
+    per (token, KV head) into ``pool[layer, 0|1]``, and the attention
+    kernels read ``pool`` at a traced ``layer``.  XLA then updates the
+    pool in place through the scan and keeps the kernels' layout, so a
+    step moves only the KV rows it writes and the pages it reads; a
+    per-layer slice as a scan operand would be sliced, copied and
+    re-stacked for every layer of every step.  Without donation each
+    dispatch materializes a second full-size pool buffer just to change a
+    few KV rows.  The donation invariant:
     a pool reference passed to a step function is DEAD on return — every
     call site here rebinds ``self.pool`` from the function's result in
     the same statement, and nothing else may retain a pool reference
@@ -556,16 +571,16 @@ class PagedModelRunner:
             x = embed_tokens(params, tokens[None]).astype(pool.dtype)  # (1,T,d)
             sin, cos = attn_mod.rope_at(positions, hd, cfg.rope_theta)
 
-            def body(xx, xs):
-                lp, pool_layer = xs
+            def body(carry, xs):
+                xx, pool = carry
+                lp, layer = xs
                 q, k, v = _layer_qkv(lp, xx, sin, cos, cfg)
                 # scatter every fresh K/V into its pool slot BEFORE
                 # attending: a token then reads earlier same-iteration
                 # tokens (its own chunk's prefix, or another chunk that
                 # shares its cached-prefix blocks) straight from the pool;
                 # padding rows carry an out-of-range slot and drop
-                kp = _write_tokens(pool_layer[0], write_slots, k[0], bs)
-                vp = _write_tokens(pool_layer[1], write_slots, v[0], bs)
+                pool = _write_tokens(pool, layer, write_slots, k[0], v[0], bs)
                 g = cfg.num_heads // cfg.num_kv_heads
                 qg = q[0].reshape(-1, cfg.num_kv_heads, g, hd)
                 # chunk rows attend as dense (Sp, L) tiles through the
@@ -576,20 +591,24 @@ class PagedModelRunner:
                 # context
                 op = kops.ragged_segment_attention(
                     qg[:tp].reshape(sp, lmax, cfg.num_kv_heads, g, hd),
-                    kp, vp, tables_p, positions_p, backend=ragged_backend)
+                    pool, layer, tables_p, positions_p, backend=ragged_backend)
                 od = kops.paged_attention(
-                    qg[tp:], kp, vp, tables_d, positions_d + 1,
+                    qg[tp:], pool, layer, tables_d, positions_d + 1,
                     backend=backend)
                 o = jnp.concatenate(
                     [op.reshape(tp, cfg.num_kv_heads, g, hd), od])
                 o = o.reshape(1, -1, cfg.num_heads * hd)
-                return _layer_finish(xx, o, lp, cfg, axis), jnp.stack([kp, vp])
+                return (_layer_finish(xx, o, lp, cfg, axis), pool), None
 
-            x, new_pool = jax.lax.scan(body, x, (params["layers"], pool))
+            # the pool is carried, not scanned over: every layer writes
+            # into and reads from the one buffer, which XLA updates in place
+            layer_ids = jnp.arange(cfg.num_layers, dtype=jnp.int32)
+            (x, pool), _ = jax.lax.scan(body, (x, pool),
+                                        (params["layers"], layer_ids))
             x = rms_norm(x, params["final_norm"], cfg.norm_eps)
             rows = x[0][sample_rows]                       # (S, d)
             logits = lm_logits(params, rows, cfg)          # (S, V)
-            return jnp.argmax(logits, -1).astype(jnp.int32), new_pool
+            return jnp.argmax(logits, -1).astype(jnp.int32), pool
 
         return step
 
@@ -607,27 +626,31 @@ class PagedModelRunner:
             ctx = jnp.where(live, positions + 1, 1).astype(jnp.int32)
             sin, cos = attn_mod.rope_at(positions[:, None], hd, cfg.rope_theta)
 
-            def body(xx, xs):
-                lp, pool_layer = xs
+            # write k/v at (table[pos // bs], pos % bs); dead batch slots
+            # point past the pool (mode="drop") so they can never stomp a
+            # live page — block tables may now be shared across sequences
+            flat = block_tables[jnp.arange(tokens.shape[0]), positions // bs] * bs \
+                + positions % bs
+            flat = jnp.where(live, flat, pool.shape[2] * bs)
+
+            def body(carry, xs):
+                xx, pool = carry
+                lp, layer = xs
                 q, k, v = _layer_qkv(lp, xx, sin, cos, cfg)
-                # write k/v at (table[pos // bs], pos % bs); dead batch slots
-                # point past the pool (mode="drop") so they can never stomp a
-                # live page — block tables may now be shared across sequences
-                flat = block_tables[jnp.arange(tokens.shape[0]), positions // bs] * bs \
-                    + positions % bs
-                flat = jnp.where(live, flat, pool_layer[0].shape[0] * bs)
-                kp = _write_tokens(pool_layer[0], flat, k[:, 0], bs)
-                vp = _write_tokens(pool_layer[1], flat, v[:, 0], bs)
+                pool = _write_tokens(pool, layer, flat, k[:, 0], v[:, 0], bs)
                 g = cfg.num_heads // cfg.num_kv_heads
                 qg = q.reshape(q.shape[0], cfg.num_kv_heads, g, hd)
-                o = kops.paged_attention(qg, kp, vp, block_tables, ctx, backend=backend)
+                o = kops.paged_attention(qg, pool, layer, block_tables, ctx,
+                                         backend=backend)
                 o = o.reshape(q.shape[0], 1, cfg.num_heads * hd)
-                return _layer_finish(xx, o, lp, cfg, axis), jnp.stack([kp, vp])
+                return (_layer_finish(xx, o, lp, cfg, axis), pool), None
 
-            x, new_pool = jax.lax.scan(body, x, (params["layers"], pool))
+            layer_ids = jnp.arange(cfg.num_layers, dtype=jnp.int32)
+            (x, pool), _ = jax.lax.scan(body, (x, pool),
+                                        (params["layers"], layer_ids))
             x = rms_norm(x, params["final_norm"], cfg.norm_eps)
             logits = lm_logits(params, x[:, 0], cfg)
-            return logits, new_pool
+            return logits, pool
 
         return step
 

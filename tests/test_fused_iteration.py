@@ -448,8 +448,12 @@ def test_ragged_segment_attention_matches_oracle(seg_specs, backend):
     """Every lowering — the native segment-tiled Pallas kernel
     ("interpret") and the legacy flatten-and-repeat lowering onto the
     decode path ("flat_*") — agrees with the ref oracle on the
-    segment-blocked causal mask."""
-    args = _ragged_case(jax.random.PRNGKey(0), seg_specs)
+    segment-blocked causal mask.  The case's pages sit in layer 1 of a
+    two-layer pool whose layer 0 is NaN."""
+    q, k_pool, v_pool, bt, pos = _ragged_case(jax.random.PRNGKey(0), seg_specs)
+    pool = jnp.stack([jnp.full((2,) + k_pool.shape, jnp.nan),
+                      jnp.stack([k_pool, v_pool])])
+    args = (q, pool, jnp.int32(1), bt, pos)
     ref = kops.ragged_segment_attention(*args, backend="ref")
     ker = kops.ragged_segment_attention(*args, backend=backend)
     np.testing.assert_allclose(np.asarray(ref), np.asarray(ker),
