@@ -3,7 +3,8 @@
 Once the window has closed and the program's state is freed, a sample
 of the measured calls that finished, drawn from the seed and always
 holding the call that served the most tokens, is run through the plain
-float32 reference (``bench/configs/<reference>.py``) over its prompt and
+float32 reference, the architecture module the configuration names
+(``bench/configs/<reference>.py``), over its prompt and
 the tokens it was served.  Served tokens are greedy, so each should be
 the reference's best token at its position, up to rounding: the number
 compared is the widest gap by which a served token's logit lies below
@@ -17,28 +18,16 @@ that it ranks first.  Every run reads it beside the program's, and
 """
 from __future__ import annotations
 
-import importlib.util
-from pathlib import Path
 from typing import List
 
 import jax.numpy as jnp
 import numpy as np
 
-from bench.model import make_weights
+from bench.model import architecture, make_weights
 
-HERE = Path(__file__).resolve().parent
 SAMPLE_CALLS = 6
 SEQ_BUCKET = 1024
 ROW_BLOCK = 512
-
-
-def reference(spec: dict, root: Path = HERE / "configs"):
-    path = root / f"{spec['reference']}.py"
-    mod_spec = importlib.util.spec_from_file_location(
-        f"bench_reference_{spec['reference']}", path)
-    mod = importlib.util.module_from_spec(mod_spec)
-    mod_spec.loader.exec_module(mod)
-    return mod
 
 
 def sample(calls, seed: int, n: int = SAMPLE_CALLS) -> list:
@@ -55,7 +44,7 @@ def sample(calls, seed: int, n: int = SAMPLE_CALLS) -> list:
 def compare(spec: dict, seed: int, calls: List) -> dict:
     """Widest served-token logit gap over the sampled calls, the
     program's and the control's."""
-    ref = reference(spec)
+    ref = architecture(spec)
     weights = make_weights(spec, seed)
     picked = sample(calls, seed)
     gaps, ctrl_gaps, n_tok, short = [], [], 0, 0

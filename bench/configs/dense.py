@@ -17,6 +17,14 @@ chip.  ``quant`` computes every matrix product with both inputs rounded
 to an 8-bit float (e4m3, one scale per row of the activations and per
 output column of the weights): the control that a lower precision must
 fail.
+
+The module is the architecture of every configuration that names it
+under ``reference``, and the harness asks it for all that depends on
+the model: ``check_registry`` (the program's entry serves the file's
+widths), ``weights`` (the parameter tree in the program's layout),
+``dims`` (the sizes the per-layer readers see, ``attn`` among them:
+the attention layers in groups of like heads and window) and
+``step_flops`` (the operations one step's served tokens need).
 """
 from __future__ import annotations
 
@@ -27,6 +35,103 @@ import jax.numpy as jnp
 
 NEG_INF = -1e30
 Q_BLOCK = 512
+
+# config-file key -> registry ModelConfig attribute
+WIDTH_KEYS = {
+    "hidden_size": "d_model",
+    "intermediate_size": "d_ff",
+    "num_hidden_layers": "num_layers",
+    "num_attention_heads": "num_heads",
+    "num_key_value_heads": "num_kv_heads",
+    "head_dim": "resolved_head_dim",
+    "vocab_size": "vocab_size",
+    "tie_word_embeddings": "tie_embeddings",
+    "rope_theta": "rope_theta",
+    "qk_norm": "qk_norm",
+}
+EPS_KEYS = ("rms_norm_eps", "layer_norm_eps")
+
+
+def eps_of(spec: dict) -> float:
+    return float(next(spec[k] for k in EPS_KEYS if k in spec))
+
+
+def check_registry(spec: dict, cfg) -> None:
+    """The program's ``ModelConfig`` must serve the file's widths; the
+    program implements RMSNorm and full-width rotary only, so the file
+    must say so (its ``departures`` list why)."""
+    bad = []
+    for key, attr in WIDTH_KEYS.items():
+        want, got = spec[key], getattr(cfg, attr)
+        if (float(want) != float(got)) if isinstance(want, (int, float)) \
+                and not isinstance(want, bool) else (bool(want) != bool(got)):
+            bad.append(f"{key}: file {want} vs registry {attr}={got}")
+    if abs(eps_of(spec) - cfg.norm_eps) > 1e-12:
+        bad.append(f"norm eps: file {eps_of(spec)} vs registry {cfg.norm_eps}")
+    if spec["norm"] != "rms" or float(spec["partial_rotary_factor"]) != 1.0:
+        bad.append("the program serves RMSNorm and full-width rotary only")
+    if cfg.family != "dense" or cfg.moe is not None or cfg.sliding_window:
+        bad.append(f"registry entry is {cfg.family}, not a dense attention stack")
+    if spec["torch_dtype"] != cfg.dtype:
+        bad.append(f"dtype: file {spec['torch_dtype']} vs registry {cfg.dtype}")
+    if bad:
+        raise SystemExit(f"config {spec['name']}: registry "
+                         f"{spec['registry']!r} differs: " + "; ".join(bad))
+
+
+def dims(spec: dict) -> dict:
+    d, h, kv = spec["hidden_size"], spec["num_attention_heads"], spec["num_key_value_heads"]
+    hd, L = spec["head_dim"], spec["num_hidden_layers"]
+    return dict(d=d, h=h, kv=kv, hd=hd, f=spec["intermediate_size"],
+                v=spec["vocab_size"], L=L,
+                tied=bool(spec["tie_word_embeddings"]), qk_norm=bool(spec["qk_norm"]),
+                attn=[dict(n=L, h=h, kv=kv, hd=hd, window=None)])
+
+
+def weights(spec: dict, key):
+    """The parameter tree in the program's layout (stacked layers)."""
+    m = dims(spec)
+    d, h, kv, hd, f, v, L = m["d"], m["h"], m["kv"], m["hd"], m["f"], m["v"], m["L"]
+    bf = jnp.bfloat16
+    leaves = iter(range(64))
+
+    def normal(shape, scale, dtype):
+        k = jax.random.fold_in(key, next(leaves))
+        return (jax.random.normal(k, shape, jnp.float32) * scale).astype(dtype)
+
+    def dense(shape):                   # (..., d_in, d_out), 1/sqrt(d_in)
+        return normal(shape, shape[-2] ** -0.5, bf)
+
+    attn = {"wq": dense((L, d, h * hd)), "wk": dense((L, d, kv * hd)),
+            "wv": dense((L, d, kv * hd)), "wo": dense((L, h * hd, d))}
+    if m["qk_norm"]:
+        attn["q_norm"] = normal((L, hd), 0.1, jnp.float32)
+        attn["k_norm"] = normal((L, hd), 0.1, jnp.float32)
+    params = {
+        "embed": normal((v, d), 0.02, bf),
+        "final_norm": normal((d,), 0.1, jnp.float32),
+        "layers": {
+            "ln1": normal((L, d), 0.1, jnp.float32),
+            "ln2": normal((L, d), 0.1, jnp.float32),
+            "attn": attn,
+            "ffn": {"w_gate": dense((L, d, f)), "w_up": dense((L, d, f)),
+                    "w_down": dense((L, f, d))},
+        },
+    }
+    if not m["tied"]:
+        params["lm_head"] = dense((d, v))
+    return params
+
+
+def step_flops(work, m: dict) -> float:
+    """Operations one step's served tokens need."""
+    d, h, kv, hd, f, v, L = m["d"], m["h"], m["kv"], m["hd"], m["f"], m["v"], m["L"]
+    per_token = 2.0 * L * (d * (h + 2 * kv) * hd + h * hd * d + 3 * d * f)
+    attn = 4.0 * L * h * hd
+    n_tok = len(work.decode_ctx) + sum(e - s for s, e in work.chunks)
+    keys = sum(work.decode_ctx) + sum((e - s) * (s + e + 1) / 2.0
+                                      for s, e in work.chunks)
+    return per_token * n_tok + attn * keys + 2.0 * d * v * work.n_emit
 
 
 def _fp8(x, axis):
@@ -114,8 +219,7 @@ def _hidden(weights, tokens, spec_key, quant):
 def _spec_key(spec: dict):
     keys = ("hidden_size", "num_attention_heads", "num_key_value_heads",
             "head_dim", "rope_theta")
-    eps = next(spec[k] for k in ("rms_norm_eps", "layer_norm_eps") if k in spec)
-    return tuple(sorted({**{k: spec[k] for k in keys}, "eps": float(eps)}.items()))
+    return tuple(sorted({**{k: spec[k] for k in keys}, "eps": eps_of(spec)}.items()))
 
 
 def hidden_states(weights, tokens, spec: dict, quant: bool = False):

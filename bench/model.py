@@ -1,78 +1,60 @@
-"""A configuration file, the model it names, and its weights.
+"""A configuration file, the architecture module it names, and its weights.
 
-``load_config`` reads ``bench/configs/<name>.json`` and checks that the
-program's registry entry it names serves exactly these widths: a
-mismatch stops the run before anything is built.  ``make_weights``
-draws every parameter on the device in one jitted call from the seed, in
-the types the program serves them in (matrices bf16, norm scales f32).
-The reference in ``bench/configs`` draws the same weights through the
-same function, never through the program.
+``load_config`` reads ``bench/configs/<name>.json``.  The file names its
+architecture module under ``reference``: ``<reference>.py`` in the same
+directory, which ``architecture`` loads.  That module holds everything
+that depends on the model (the plain reference, the registry check, the
+weight tree, the sizes the readers see and the work a step needs), so a
+new architecture is a new module and a new file, and nothing here
+changes.  ``registry_config`` stops the run before anything is built
+when the program's registry entry does not serve the file's widths.
+``make_weights`` draws every parameter on the device in one jitted call
+from the seed, through the module, never through the program.
 """
 from __future__ import annotations
 
+import dataclasses
+import functools
+import importlib.util
 import json
 from pathlib import Path
 from typing import Optional
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 HERE = Path(__file__).resolve().parent
 
-# config-file key -> (registry ModelConfig attribute, how to read it)
-WIDTH_KEYS = {
-    "hidden_size": "d_model",
-    "intermediate_size": "d_ff",
-    "num_hidden_layers": "num_layers",
-    "num_attention_heads": "num_heads",
-    "num_key_value_heads": "num_kv_heads",
-    "head_dim": "resolved_head_dim",
-    "vocab_size": "vocab_size",
-    "tie_word_embeddings": "tie_embeddings",
-    "rope_theta": "rope_theta",
-    "qk_norm": "qk_norm",
-}
-EPS_KEYS = ("rms_norm_eps", "layer_norm_eps")
+
+@functools.lru_cache(maxsize=None)
+def load_module(path: Path):
+    """The Python file at ``path`` as a module, loaded once per process."""
+    spec = importlib.util.spec_from_file_location(
+        f"bench_{path.parent.name}_{path.stem}".replace(".", "_"), path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 def load_config(name: str, root: Optional[Path] = None) -> dict:
-    spec = json.loads(((root or HERE / "configs") / f"{name}.json").read_text())
+    """The configuration ``<name>.json`` in ``root``, with the directory
+    it came from under ``config_dir``."""
+    root = Path(root or HERE / "configs").resolve()
+    spec = json.loads((root / f"{name}.json").read_text())
     assert spec["name"] == name, f"{name}.json names {spec['name']!r}"
+    spec["config_dir"] = str(root)
     return spec
 
 
-def eps_of(spec: dict) -> float:
-    return float(next(spec[k] for k in EPS_KEYS if k in spec))
-
-
-def check_registry(spec: dict, cfg) -> None:
-    """The program's ``ModelConfig`` must serve the file's widths; the
-    program implements RMSNorm and full-width rotary only, so the file
-    must say so (its ``departures`` list why)."""
-    bad = []
-    for key, attr in WIDTH_KEYS.items():
-        want, got = spec[key], getattr(cfg, attr)
-        if (float(want) != float(got)) if isinstance(want, (int, float)) \
-                and not isinstance(want, bool) else (bool(want) != bool(got)):
-            bad.append(f"{key}: file {want} vs registry {attr}={got}")
-    if abs(eps_of(spec) - cfg.norm_eps) > 1e-12:
-        bad.append(f"norm eps: file {eps_of(spec)} vs registry {cfg.norm_eps}")
-    if spec["norm"] != "rms" or float(spec["partial_rotary_factor"]) != 1.0:
-        bad.append("the program serves RMSNorm and full-width rotary only")
-    if cfg.family != "dense" or cfg.moe is not None or cfg.sliding_window:
-        bad.append(f"registry entry is {cfg.family}, not a dense attention stack")
-    if spec["torch_dtype"] != cfg.dtype:
-        bad.append(f"dtype: file {spec['torch_dtype']} vs registry {cfg.dtype}")
-    if bad:
-        raise SystemExit(f"config {spec['name']}: registry "
-                         f"{spec['registry']!r} differs: " + "; ".join(bad))
+def architecture(spec: dict):
+    """The module the configuration names, from beside its file."""
+    return load_module(Path(spec["config_dir"]) / f"{spec['reference']}.py")
 
 
 def registry_config(spec: dict):
     from repro.configs import get_config
     cfg = get_config(spec["registry"])
-    check_registry(spec, cfg)
+    architecture(spec).check_registry(spec, cfg)
     return cfg
 
 
@@ -85,58 +67,19 @@ def seed_key(seed: int) -> jax.Array:
     return key
 
 
-def dims(spec: dict) -> dict:
-    d, h, kv = spec["hidden_size"], spec["num_attention_heads"], spec["num_key_value_heads"]
-    return dict(d=d, h=h, kv=kv, hd=spec["head_dim"], f=spec["intermediate_size"],
-                v=spec["vocab_size"], L=spec["num_hidden_layers"],
-                tied=bool(spec["tie_word_embeddings"]), qk_norm=bool(spec["qk_norm"]))
-
-
-def _weights(spec: dict, key):
-    """The parameter tree in the program's layout (stacked layers)."""
-    m = dims(spec)
-    d, h, kv, hd, f, v, L = m["d"], m["h"], m["kv"], m["hd"], m["f"], m["v"], m["L"]
-    bf = jnp.bfloat16
-    leaves = iter(range(64))
-
-    def normal(shape, scale, dtype):
-        k = jax.random.fold_in(key, next(leaves))
-        return (jax.random.normal(k, shape, jnp.float32) * scale).astype(dtype)
-
-    def dense(shape):                   # (..., d_in, d_out), 1/sqrt(d_in)
-        return normal(shape, shape[-2] ** -0.5, bf)
-
-    attn = {"wq": dense((L, d, h * hd)), "wk": dense((L, d, kv * hd)),
-            "wv": dense((L, d, kv * hd)), "wo": dense((L, h * hd, d))}
-    if m["qk_norm"]:
-        attn["q_norm"] = normal((L, hd), 0.1, jnp.float32)
-        attn["k_norm"] = normal((L, hd), 0.1, jnp.float32)
-    params = {
-        "embed": normal((v, d), 0.02, bf),
-        "final_norm": normal((d,), 0.1, jnp.float32),
-        "layers": {
-            "ln1": normal((L, d), 0.1, jnp.float32),
-            "ln2": normal((L, d), 0.1, jnp.float32),
-            "attn": attn,
-            "ffn": {"w_gate": dense((L, d, f)), "w_up": dense((L, d, f)),
-                    "w_down": dense((L, f, d))},
-        },
-    }
-    if not m["tied"]:
-        params["lm_head"] = dense((d, v))
-    return params
-
-
 def make_weights(spec: dict, seed: int):
     """Every weight, drawn on the default device by one jitted call."""
-    fn = jax.jit(lambda k: _weights(spec, k))
+    arch = architecture(spec)
+    fn = jax.jit(lambda k: arch.weights(spec, k))
     return jax.block_until_ready(fn(seed_key(seed)))
 
 
 def serving_config(spec: dict):
+    """``ServingConfig`` from every key of the file's ``serving`` group;
+    a key the program's ``ServingConfig`` lacks stops the run."""
     from repro.serving import ServingConfig
-    s = spec["serving"]
-    return ServingConfig(num_blocks=s["num_blocks"], block_size=s["block_size"],
-                         max_batch=s["max_batch"],
-                         prefill_chunk_tokens=s["prefill_chunk_tokens"],
-                         prefix_caching=s["prefix_caching"], policy=s["policy"])
+    known = {f.name for f in dataclasses.fields(ServingConfig)}
+    unknown = sorted(set(spec["serving"]) - known)
+    if unknown:
+        raise SystemExit(f"config {spec['name']}: ServingConfig has no {unknown}")
+    return ServingConfig(**spec["serving"])

@@ -4,8 +4,10 @@
     python bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 The cell is an entry of ``workloads`` in ``BENCHMARK.json`` at the root
-of the checkout; it names a configuration (``bench/configs/<name>.json``)
-and a traffic mix (``bench/traffic/<name>.json``).  Per-layer metrics
+of the checkout; it names a configuration (``bench/configs/<name>.json``,
+whose architecture module beside it supplies the reference, the weights,
+the registry check, the readers' sizes and a step's work) and a traffic
+mix (``bench/traffic/<name>.json``).  Per-layer metrics
 are read by ``bench/metrics/<metric>.py``, kernel work is counted by
 ``bench/kernels/<kernel>.py``, peaks come from ``bench/peaks.json``.
 
@@ -13,7 +15,9 @@ A run: build the cluster (``ServingCluster.from_config``, policy kairos)
 over weights drawn on the device from the seed; warm up by replaying
 the window's own schedule with fresh tokens until it has finished, twice
 (this compiles the step shapes the window uses, fills the prefix cache,
-and gives the orchestrator its latency samples); then offer the schedule
+and gives the orchestrator its latency samples); issue one padding-only
+step of every shape the window can reach that the replay did not
+(``bench/shapes.py``), so that nothing compiles in the window; then offer the schedule
 open-loop for ``--seconds`` and keep it running until the window's
 workflows have finished or the mix's cap is reached.  ``setup_s`` runs
 from process start to the window's start.  With ``--trace 1`` the last
@@ -37,7 +41,6 @@ import argparse  # noqa: E402
 import contextlib  # noqa: E402
 import dataclasses  # noqa: E402
 import gc  # noqa: E402
-import importlib.util  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
 import shutil  # noqa: E402
@@ -138,8 +141,10 @@ class CompileCounter:
 class Ctx:
     """What a per-layer reader sees."""
 
-    def __init__(self, calls, snap0, snap1, trace, work, m, peaks, pool_live=()):
+    def __init__(self, calls, snap0, snap1, trace, work, m, peaks, pool_live=(),
+                 arch=None):
         self.calls, self.trace, self.work, self.m, self.peaks = calls, trace, work, m, peaks
+        self.arch = arch                     # the configuration's architecture module
         self._s0, self._s1 = snap0, snap1
         self.pool_live = list(pool_live)     # share of the pool held by running calls
 
@@ -158,11 +163,8 @@ class Ctx:
 
 
 def read_metric(name: str, ctx):
-    path = BENCH / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(f"bench_metric_{name}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read(ctx)
+    from bench.model import load_module
+    return load_module(BENCH / "metrics" / f"{name}.py").read(ctx)
 
 
 def build(spec: dict, seed: int):
@@ -183,10 +185,6 @@ def build(spec: dict, seed: int):
     return cluster, orch
 
 
-def jit_shapes(cluster) -> int:
-    return sum(e.runner.jit_cache_size() for e in cluster.engines)
-
-
 def live_share(engines) -> float:
     """Share of the engines' KV pools neither free nor parked in the
     prefix cache."""
@@ -201,12 +199,14 @@ def run_cell(spec: dict, mix: dict, seed: int, seconds: float, trace: bool,
     from bench import stats
     from bench.check import compare, verdict
     from bench.driver import Driver
-    from bench.model import dims
+    from bench.model import architecture
+    from bench.shapes import StepShapes, reachable, warm as warm_shapes
     from bench.traffic.gen import Prompts, schedule
 
     counter = CompileCounter.get()
     reset_request_ids()
     cluster, orch = build(spec, seed)
+    shapes = StepShapes(cluster.engines, log)
     flows = schedule(mix, seconds)
     prompts = Prompts(mix, seed, spec["vocab_size"])
     cap = seconds + float(mix["follow_cap_s"])
@@ -219,7 +219,7 @@ def run_cell(spec: dict, mix: dict, seed: int, seconds: float, trace: bool,
     # it meets the step shapes of the window's own timing
     t_warm = time.monotonic()
     for k in range(WARMUP_PASSES):
-        t_pass, shapes0 = time.monotonic(), jit_shapes(cluster)
+        t_pass, shapes0 = time.monotonic(), len(shapes.all_issued())
         wd = Driver(cluster, orch,
                     [dataclasses.replace(f, wid=f.wid + k * len(flows),
                                          seed_offset=1000 + k) for f in warm],
@@ -228,9 +228,15 @@ def run_cell(spec: dict, mix: dict, seed: int, seconds: float, trace: bool,
         wd.run()
         if cluster.has_work:
             raise SystemExit(f"warm-up did not finish within {WARMUP_CAP_S:g}s")
-        new = jit_shapes(cluster) - shapes0
+        new = len(shapes.all_issued()) - shapes0
         log(f"[setup] warm-up pass {k + 1}: {time.monotonic() - t_pass:.3f}s, "
             f"{wd.n_steps} steps, {new} new step shapes; compiles so far {counter.snap()}")
+    shapes.replay = shapes.all_issued()
+    log(f"[setup] replay step shapes: {sorted(list(k) for k in shapes.replay)}")
+    t_shapes = time.monotonic()
+    n_warm = warm_shapes(cluster.engines, shapes,
+                         reachable(shapes.replay))
+    log(f"[setup] shape warm: {n_warm} shapes, {time.monotonic() - t_shapes:.3f} s")
     log(f"[setup] build {t_warm - T_START:.3f}s, warm-up {time.monotonic() - t_warm:.3f}s")
 
     # ---- the window and its follow-through
@@ -254,6 +260,7 @@ def run_cell(spec: dict, mix: dict, seed: int, seconds: float, trace: bool,
         if "w0" not in marks:
             marks["w0"] = (cluster.metrics_snapshot(), counter.snap(),
                            time.monotonic())
+            shapes.window_t0 = drv.t0
         if "w1" not in marks and t >= seconds:
             marks["w1"] = (cluster.metrics_snapshot(), counter.snap())
         if trace and not tracing["on"] and "traced" not in marks and t >= t_lo:
@@ -284,6 +291,9 @@ def run_cell(spec: dict, mix: dict, seed: int, seconds: float, trace: bool,
     log(f"[window] {seconds:g}s: {len(mflows)} workflows, {len(measured)} calls "
         f"measured; {failed} calls unfinished at the cap; {drv.n_steps} steps; "
         f"follow-through ended {t_end - drv.t0 - seconds:.3f}s after the window")
+    log(f"[window] step shapes unmet by the replay: "
+        f"{sorted(list(k) for k in shapes.window_keys - shapes.replay)}; "
+        f"{len(shapes.window_new)} of them new to the runners")
     log(f"[window] compilations inside the window: {in_window} "
         f"(engine jit shapes {snap0.get('engine0.jit_cache_size', 0):.0f} -> "
         f"{snap1.get('engine0.jit_cache_size', 0):.0f})")
@@ -328,8 +338,9 @@ def run_cell(spec: dict, mix: dict, seed: int, seconds: float, trace: bool,
             f"{len(red['steps'])} fused steps paired with {red['n_step_spans']} "
             f"bench.step spans and {len(drv.work)} work records; idle by host span "
             f"{red['idle']}")
-        m = dict(dims(spec), block_size=spec["serving"]["block_size"])
-        ctx = Ctx(measured, snap0, snap1, red, drv.work, m, peaks, pool_live)
+        arch = architecture(spec)
+        m = dict(arch.dims(spec), block_size=spec["serving"]["block_size"])
+        ctx = Ctx(measured, snap0, snap1, red, drv.work, m, peaks, pool_live, arch)
         for name in metric_names:
             v = read_metric(name["name"], ctx)
             if v is not None:

@@ -6,7 +6,8 @@ import pytest
 
 from bench import stats
 from bench.driver import Call, StepWork
-from bench.roofline import kernel_cost, step_flops
+from bench.model import architecture, load_config
+from bench.roofline import kernel_cost
 from bench.traffic.gen import Flow, Stage
 
 
@@ -41,7 +42,8 @@ def test_workflow_e2e_and_rate():
     assert stats.rate(300, 30.0) == pytest.approx(10.0)
 
 
-M = dict(d=8, h=4, kv=2, hd=16, f=32, v=100, L=3, block_size=16)
+M = dict(d=8, h=4, kv=2, hd=16, f=32, v=100, L=3, block_size=16,
+         attn=[dict(n=3, h=4, kv=2, hd=16, window=None)])
 
 
 def test_paged_attention_work():
@@ -59,6 +61,7 @@ def test_ragged_attention_work():
 
 
 def test_step_flops():
+    step_flops = architecture(load_config("qwen3-1.7b")).step_flops
     w = StepWork([5], [(0, 4)], 2)
     per_tok = 2 * 3 * (8 * (4 + 4) * 16 + 4 * 16 * 8 + 3 * 8 * 32)
     attn = 4 * 3 * 4 * 16 * (5 + (1 + 2 + 3 + 4))

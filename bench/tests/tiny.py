@@ -10,7 +10,8 @@ BENCH = Path(__file__).resolve().parents[1]
 
 def tiny_spec(limit: float = 1.0) -> dict:
     """qwen3-1.7b's registry entry at its CPU test size (``reduced()``)."""
-    spec = json.loads((BENCH / "configs" / "qwen3-1.7b.json").read_text())
+    from bench.model import load_config
+    spec = load_config("qwen3-1.7b")
     spec.update({"hidden_size": 256, "intermediate_size": 512,
                  "num_hidden_layers": 2, "num_attention_heads": 4, "num_key_value_heads": 2,
                  "head_dim": 64, "vocab_size": 512})
@@ -22,11 +23,12 @@ def tiny_spec(limit: float = 1.0) -> dict:
 
 def tiny_registry(spec: dict):
     """The registry entry cut to ``reduced()``, checked against the spec
-    like a cell's; stands in for ``bench.model.registry_config``."""
-    from bench.model import check_registry
+    like a cell's by its architecture module; stands in for
+    ``bench.model.registry_config``."""
+    from bench.model import architecture
     from repro.configs import get_config
     cfg = get_config(spec["registry"]).reduced()
-    check_registry(spec, cfg)
+    architecture(spec).check_registry(spec, cfg)
     return cfg
 
 
